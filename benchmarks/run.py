@@ -68,6 +68,8 @@ def main(argv=None) -> int:
                          "loadable) here; also prints a text flame "
                          "summary (src/repro/telemetry/)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (batchsize, fig5_hardware, fig12_breakdown,
                             fig34_compilers, history_report, loadgen_curve,

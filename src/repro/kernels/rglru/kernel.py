@@ -37,16 +37,19 @@ def _rglru_kernel(a_ref, b_ref, h_ref, state_scr, *, block_t: int):
     a = a_ref[0].astype(jnp.float32)          # (L, D)
     b = b_ref[0].astype(jnp.float32)          # (L, D)
     log_a = jnp.log(jnp.maximum(a, 1e-37))
-    cum = jnp.cumsum(log_a, axis=0)           # (L, D)
-    # decay(i, j) = exp(cum_i - cum_j) for j <= i  (per feature lane)
-    seg = cum[:, None, :] - cum[None, :, :]   # (L, L, D)
     ii = jax.lax.broadcasted_iota(jnp.int32, (block_t, block_t, 1), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (block_t, block_t, 1), 1)
-    w = jnp.where(jj <= ii, jnp.exp(seg), 0.0)
+    lower = jj <= ii
+    # inclusive prefix sum over time as a lower-triangular masked reduction
+    # (the TPU lowering has no cumsum)
+    cum = jnp.sum(jnp.where(lower, log_a[None, :, :], 0.0), axis=1)  # (L, D)
+    # decay(i, j) = exp(cum_i - cum_j) for j <= i  (per feature lane)
+    seg = cum[:, None, :] - cum[None, :, :]   # (L, L, D)
+    w = jnp.where(lower, jnp.exp(seg), 0.0)
     h = jnp.sum(w * b[None, :, :], axis=1)    # (L, D)
     h = h + jnp.exp(cum) * state_scr[...]
     h_ref[0] = h.astype(h_ref.dtype)
-    state_scr[...] = h[-1]
+    state_scr[...] = h[block_t - 1:]          # (1, D) carried state
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_d", "interpret"))
@@ -76,6 +79,6 @@ def rglru_scan_kernel(a, b, *, block_t: int = 16, block_d: int = 128,
         ],
         out_specs=pl.BlockSpec((1, block_t, block_d), lambda bb, d, t: (bb, t, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, D), a.dtype),
-        scratch_shapes=[pltpu.VMEM((block_d,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
         interpret=interpret,
     )(a, b)

@@ -36,18 +36,24 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *,
 
     x = x_ref[0].astype(jnp.float32)          # (L, P)
     dt = dt_ref[0].astype(jnp.float32)        # (L, 1)
-    a = a_ref[0, 0]                           # scalar A (negative)
+    a = a_ref[0]                              # (1, 1) A (negative)
     bm = b_ref[0].astype(jnp.float32)         # (L, N)
     cm = c_ref[0].astype(jnp.float32)         # (L, N)
 
     da = dt * a                                # (L, 1) log-decay
-    cum = jnp.cumsum(da, axis=0)               # (L, 1)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sums as masked reductions (the TPU lowering has no
+    # cumsum): a row cum_row[j] = sum_{i<=j} da_i, then the same values as
+    # a column through the diagonal (no vector transpose either)
+    cum_row = jnp.sum(jnp.where(ii <= jj, da, 0.0), axis=0,
+                      keepdims=True)           # (1, L)
+    cum = jnp.sum(jnp.where(ii == jj, cum_row, 0.0), axis=1,
+                  keepdims=True)               # (L, 1)
     # intra-chunk: w[i,j] = exp(cum_i - cum_j) * (C_i . B_j), j <= i
     scores = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # (L, L)
-    seg = cum - cum.T                          # (L, L) cum_i - cum_j
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    seg = cum - cum_row                        # (L, L) cum_i - cum_j
     w = jnp.where(jj <= ii, jnp.exp(seg) * scores, 0.0)
     xdt = x * dt                               # (L, P)
     y_intra = jax.lax.dot_general(w, xdt, (((1,), (0,)), ((), ())),
@@ -61,13 +67,13 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *,
     decay_end = jnp.exp(cum[-1:] - cum)        # (L, 1)
     upd = jax.lax.dot_general(bm * decay_end, xdt, (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)   # (N, P)
-    state_scr[...] = jnp.exp(cum[-1, 0]) * state + upd
+    state_scr[...] = jnp.exp(cum[-1:]) * state + upd
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_bh(x, dt, a, bm, cm, *, chunk: int = 128,
            interpret: Optional[bool] = None):
-    """x (BH, S, P), dt (BH, S, 1), a (BH, 1), bm/cm (BH, S, N) -> y (BH, S, P).
+    """x (BH, S, P), dt (BH, S, 1), a (BH, 1, 1), bm/cm (BH, S, N) -> y (BH, S, P).
 
     The carried state scratch makes the chunk grid sequential, so S must
     be a multiple of chunk — validated with a clear error (``ops.ssd``
@@ -86,7 +92,10 @@ def ssd_bh(x, dt, a, bm, cm, *, chunk: int = 128,
         in_specs=[
             pl.BlockSpec((1, chunk, P), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, 1), lambda b, c: (b, 0)),
+            # one scalar per (batch, head) as a full-dimension (1, 1)
+            # block: a (1, 1) block of a (BH, 1) array is not aligned to
+            # the (8, 128) tiling
+            pl.BlockSpec((1, 1, 1), lambda b, c: (b, 0, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, c: (b, c, 0)),
         ],

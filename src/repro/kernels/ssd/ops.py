@@ -53,7 +53,7 @@ def ssd(x, dt, A, Bm, Cm, *, chunk: Optional[int] = None,
     Sp = S + pad
     xf = x.transpose(0, 2, 1, 3).reshape(B * H, Sp, P)
     dtf = dt.transpose(0, 2, 1).reshape(B * H, Sp, 1)
-    af = jnp.broadcast_to(A[None, :], (B, H)).reshape(B * H, 1)
+    af = jnp.broadcast_to(A[None, :], (B, H)).reshape(B * H, 1, 1)
     bf = jnp.repeat(Bm[:, None], H, axis=1).reshape(B * H, Sp, N)
     cf = jnp.repeat(Cm[:, None], H, axis=1).reshape(B * H, Sp, N)
     y = ssd_bh(xf, dtf, af, bf, cf, chunk=L, interpret=interpret)

@@ -1,12 +1,17 @@
 import os
 import sys
-_DUMP_DIR = f"/tmp/repro_hlo_dump_{os.getpid()}"
+import tempfile
+_DUMP_DIR = os.path.join(tempfile.gettempdir(), f"repro_hlo_dump_{os.getpid()}")
 # The 512 placeholder devices are needed only where cells actually compile:
 # the ``python -m repro.launch.dryrun`` subprocess and scripts/dump_cell.py.
 # Under pytest this module is imported for its pure helpers (cell_rules,
 # input_specs) and the flags must NOT leak into the test process — tests
 # measure on the single real CPU device (see tests/conftest.py).
+# The dry run is a host-device compile by construction: it pins the CPU
+# platform, so on a machine with a TPU it never tries to load libtpu
+# (which the parent process may hold).
 if "pytest" not in sys.modules:
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (
         "--xla_force_host_platform_device_count=512 "
         f"--xla_dump_to={_DUMP_DIR} --xla_dump_hlo_pass_re=spmd-partitioning"
@@ -236,8 +241,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
     mem = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # [dict] on some jax versions
-        ca = ca[0] if ca else {}
     cost, cost_src = _analyze_post_spmd(compiled)
     rl = roofline_from_cost(
         cost, arch=arch, shape=shape_name, mesh=_mesh_name(multi_pod),

@@ -466,6 +466,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.core.suite import build_arch
     from repro.configs import get_arch
     if args.full:

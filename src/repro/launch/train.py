@@ -119,6 +119,8 @@ def main(argv=None) -> int:
     ap.add_argument("--inject-fault-at", type=int, default=None)
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     out = train(args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
                 reduced=not args.full, ckpt_dir=args.ckpt_dir,
                 inject_fault_at=args.inject_fault_at)

@@ -47,6 +47,25 @@ from repro.telemetry.provenance import stamp as stamp_provenance
 from repro.telemetry.spans import NULL_TRACER, Tracer, group_label
 
 
+def _check_in_process(*, jobs: int = 0, cluster: str = "",
+                      isolate: bool = False) -> None:
+    """Refuse multi-process dispatch on a TPU backend.  A chip belongs to
+    one process: this one holds it as soon as it has touched JAX, so a
+    pool, cluster or isolated worker that needs the chip would fail or
+    hang.  On a TPU, run cells in process."""
+    what = [name for name, on in (("jobs>1", jobs and jobs > 1),
+                                  ("cluster", bool(cluster)),
+                                  ("isolate", isolate)) if on]
+    if not what:
+        return
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"BenchmarkRunner: {', '.join(what)} starts worker processes "
+            f"that need the TPU this process holds; on a TPU run cells in "
+            f"process (no jobs, cluster or isolate)")
+
+
 @dataclasses.dataclass
 class RunnerStats:
     model_builds: int = 0
@@ -148,6 +167,7 @@ class BenchmarkRunner:
         self._prof_costs: Dict[Any, Any] = {}
         self._pool: Optional[ShardScheduler] = None
         self._cluster: Optional[Any] = None   # ClusterScheduler, lazy
+        _check_in_process(jobs=jobs, cluster=cluster, isolate=isolate)
 
     def close(self) -> None:
         """Shut down the persistent shard workers and the cluster
@@ -695,6 +715,7 @@ class BenchmarkRunner:
         scenarios = self.select(matrix)
         jobs = self.jobs if jobs is None else jobs
         cluster = self.cluster if cluster is None else cluster
+        _check_in_process(jobs=jobs, cluster=cluster)
         extras = self._matrix_extras(matrix, scenarios)
         tr = self.tracer
         if tr.enabled:

@@ -107,7 +107,12 @@ def _file_lock(path):
 
 
 def _build_runner(args):
+    """The worker's in-process runner.  Every mode of ``main()`` builds it
+    before anything compiles, so the compile cache is enabled here — after
+    a cluster worker has registered, since it pulls in jax."""
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.runner.runner import BenchmarkRunner
+    enable_compile_cache()
     return BenchmarkRunner(runs=args.runs, warmup=args.warmup,
                            compile_warmup=args.compile_warmup,
                            reuse=args.reuse)

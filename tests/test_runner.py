@@ -329,3 +329,22 @@ def test_runner_donated_scenario_repeats(tmp_path):
     for _ in range(3):
         assert r.run(sc).status == "ok"
     assert r.stats.executable_cache_hits == 2
+
+
+# ---- one process per chip ---------------------------------------------------
+
+@pytest.mark.parametrize("kw", [{"jobs": 2}, {"cluster": "local:2"},
+                                {"isolate": True}],
+                         ids=["jobs", "cluster", "isolate"])
+def test_multiprocess_dispatch_refused_on_tpu(monkeypatch, kw):
+    """Worker processes cannot get a chip the parent holds: on a TPU
+    backend the runner refuses them instead of failing or hanging."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="TPU"):
+        BenchmarkRunner(**kw)
+    runner = BenchmarkRunner()   # in process: fine
+    if "isolate" not in kw:      # run_matrix's per-call dispatch overrides
+        m = ScenarioMatrix(archs=["gemma-2b"], tasks=("train",))
+        with pytest.raises(RuntimeError, match="TPU"):
+            runner.run_matrix(m, **kw)
