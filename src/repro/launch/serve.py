@@ -78,6 +78,39 @@ ADMIT_MIN_BUCKET = 8
 #: valid values of the engine's ``admission`` policy flag
 ADMISSIONS = ("batched", "single")
 
+#: the host phases of one decode step, in the order they run; together they
+#: tile the step but for ``hook.fire()``, which lies between sync and commit
+DECODE_PHASES = ("decode.prepare", "decode.dispatch", "decode.sync",
+                 "decode.commit")
+#: the host phases of admission: ``admit.pack`` then ``admit.prefill`` per
+#: bucket group (``admit.compile`` in its place for a (rows, padded_len)
+#: shape the engine has not run before), then one ``admit.commit`` per wave
+ADMIT_PHASES = ("admit.pack", "admit.prefill", "admit.compile",
+                "admit.commit")
+
+
+def _mark(span_log: list, name: str, t0: float,
+          attrs: Optional[Dict[str, int]] = None) -> float:
+    """Append span ``name`` from ``t0`` to now; returns now."""
+    t1 = time.time()
+    span_log.append((name, t0, t1) if attrs is None else (name, t0, t1, attrs))
+    return t1
+
+
+def decode_phase_log(span_log: list) -> List[Tuple[float, float]]:
+    """``(dispatch_s, device_s)`` per decode step of a ``run()`` span log:
+    ``decode.prepare`` + ``decode.dispatch`` until the async call returns,
+    then ``decode.sync``, the wait for the device and the readback."""
+    out: List[Tuple[float, float]] = []
+    host = 0.0
+    for name, t0, t1, *_ in span_log:
+        if name in ("decode.prepare", "decode.dispatch"):
+            host += t1 - t0
+        elif name == "decode.sync":
+            out.append((host, t1 - t0))
+            host = 0.0
+    return out
+
 
 class ServeEngine:
     """Slot-based continuous batching over a shared decode step.
@@ -187,14 +220,18 @@ class ServeEngine:
             shape[ax] = self.slots
             return jnp.where(mask.reshape(shape), rows, big)
 
-        cache = jax.tree.map(scatter, cache, mini, self._cache_axes)
+        with jax.named_scope("admit_scatter"):
+            cache = jax.tree.map(scatter, cache, mini, self._cache_axes)
         return first, cache
 
-    def _admit_wave(self, pairs: List[Tuple[int, Request]]) -> List[int]:
-        """Admit a wave of (slot, request) pairs; returns their first
-        tokens in pair order.  Batched admission groups the wave by
-        prompt-length bucket — one jitted call per group; single admission
-        degrades to one exact-length call per request."""
+    def _admit_wave(self, pairs: List[Tuple[int, Request]],
+                    span_log: Optional[list] = None) -> List[int]:
+        """Prefill a wave of (slot, request) pairs into the live cache;
+        returns their first tokens in pair order.  Batched admission groups
+        the wave by prompt-length bucket — one jitted call per group;
+        single admission degrades to one exact-length call per request.
+        ``span_log`` receives each group's ``admit.pack`` and
+        ``admit.prefill``/``admit.compile`` spans (see ``run()``)."""
         if self.admission == "single":
             grouped = [[pr] for pr in pairs]
         else:
@@ -204,7 +241,10 @@ class ServeEngine:
                                      []).append(pr)
             grouped = [by_bucket[b] for b in sorted(by_bucket)]
         first_by_slot: Dict[int, int] = {}
+        t = 0.0
         for grp in grouped:
+            if span_log is not None:
+                t = time.time()
             lpad = self._bucket(max(len(r.prompt) for _, r in grp))
             kb = len(grp)
             if self.admission == "batched":
@@ -220,16 +260,21 @@ class ServeEngine:
                 lengths[i] = len(r.prompt)
                 src[s] = i
                 mask[s] = True
+            if span_log is not None:
+                t = _mark(span_log, "admit.pack", t)
             first, self.cache = self._admit(
                 self.params, jnp.asarray(tokens), jnp.asarray(lengths),
                 jnp.asarray(src), jnp.asarray(mask), self.cache)
             first = np.asarray(first)
+            if span_log is not None:
+                new_shape = (kb, lpad) not in self._admit_shapes
+                _mark(span_log, "admit.compile" if new_shape else "admit.prefill",
+                      t, {"requests": len(grp), "rows": kb, "padded_len": lpad,
+                          "valid_tokens": int(lengths[:len(grp)].sum())})
             self._admit_calls += 1
             self._admit_batches.append(len(grp))
             self._admit_shapes.add((kb, lpad))
-            for i, (s, r) in enumerate(grp):
-                self.slot_req[s] = r
-                self.slot_pos[s] = self._prefix + len(r.prompt)
+            for i, (s, _) in enumerate(grp):
                 first_by_slot[s] = int(first[i])
         return [first_by_slot[s] for s, _ in pairs]
 
@@ -241,7 +286,6 @@ class ServeEngine:
         return self._decode.lower(self.params, toks, self.cache)
 
     def run(self, requests: List[Request], *, hook=None,
-            phase_log: Optional[list] = None,
             span_log: Optional[list] = None) -> Dict[str, Any]:
         """Replay a trace; returns throughput + raw latency samples.
 
@@ -253,14 +297,28 @@ class ServeEngine:
         timestamps are stamped alongside for the latency metrics.
 
         ``hook`` is an optional ``RegressionHook`` fired once per decode
-        step, so injected-slowdown CI probes work on serve cells too.
-        ``phase_log`` is the profiler hook: one ``(dispatch_s, device_s)``
-        tuple per batched decode step — the split is taken only when a log
-        is passed, so unprofiled replays keep the pre-profiler timing.
-        ``span_log`` is the tracing hook: one ``(name, wall_t0, wall_t1)``
-        tuple per admission wave ("admit_wave") and batched decode step
-        ("decode_step"); wall-clock reads happen only when a list is
-        passed, so untraced replays pay nothing.
+        step, after the readback and before the rows advance, so
+        injected-slowdown CI probes work on serve cells too.
+
+        ``span_log`` is the tracing hook: it receives ``(name, t0, t1)``
+        or ``(name, t0, t1, attrs)`` tuples that tile the loop's host work
+        without overlapping.  Each decode step logs ``DECODE_PHASES`` in
+        order: ``decode.prepare`` (the KV-exhaustion guard and the token
+        upload), ``decode.dispatch`` (the jitted decode call, until it
+        returns), ``decode.sync`` (the argmax and its readback: the host
+        waits on the device) and ``decode.commit`` (appending tokens,
+        finishing requests, advancing the rows).  ``hook.fire()`` runs
+        between sync and commit, outside every span: its time is the
+        caller's.  Admission logs ``ADMIT_PHASES``: ``admit.pack`` and
+        ``admit.prefill`` per bucket group — ``admit.compile`` for a
+        (rows, padded_len) shape this engine had not run before — with
+        ``requests``/``rows``/``padded_len``/``valid_tokens`` attrs, then
+        one ``admit.commit`` per wave for slot assignment and the
+        first-token and TTFT bookkeeping.  Times are ``time.time()``: a
+        profiler stamps its events on another base, but both clocks
+        advance at the same rate, so one annotation whose ``time.time()``
+        is known puts every span on the trace's clock.  With
+        ``span_log=None`` the engine reads no extra clock.
         """
         self._reset()
         shapes0 = len(self._admit_shapes)
@@ -276,6 +334,7 @@ class ServeEngine:
         tok_lat_s: List[float] = []
         qdepth: List[int] = []
         waves = 0
+        t = 0.0      # start of the open span, when span_log is given
         t0 = time.perf_counter()
         while done_count < total:
             now = time.perf_counter()
@@ -298,13 +357,13 @@ class ServeEngine:
                 if pairs:
                     del waiting[: len(pairs)]
                     waves += 1
-                    tw = time.time() if span_log is not None else 0.0
-                    firsts = self._admit_wave(pairs)
+                    firsts = self._admit_wave(pairs, span_log)
                     if span_log is not None:
-                        span_log.append(("admit_wave", tw, time.time(),
-                                         {"requests": len(pairs)}))
+                        t = time.time()
                     tnow = time.perf_counter()
                     for (s, req), tok in zip(pairs, firsts):
+                        self.slot_req[s] = req
+                        self.slot_pos[s] = self._prefix + len(req.prompt)
                         req.out.append(tok)
                         tokens_out += 1
                         req.t_first = tnow
@@ -316,10 +375,14 @@ class ServeEngine:
                             req.t_done = tnow
                             active -= 1
                             done_count += 1
+                    if span_log is not None:
+                        _mark(span_log, "admit.commit", t)
             qdepth.append(len(waiting))
             if active == 0:
                 step += 1
                 continue
+            if span_log is not None:
+                t = time.time()
             for s in range(self.slots):
                 req = self.slot_req[s]
                 if req is None or req.done:
@@ -331,20 +394,20 @@ class ServeEngine:
                         f"position {int(self.slot_pos[s])} with max_len "
                         f"{self.max_len} — size the engine with "
                         f"traces.cache_len_bound() for the trace")
-            tw = time.time() if span_log is not None else 0.0
             ts = time.perf_counter()
             toks = jnp.asarray(next_tok[:, None])
+            if span_log is not None:
+                t = _mark(span_log, "decode.prepare", t)
             logits, self.cache = self._decode(self.params, toks, self.cache)
-            t_disp = time.perf_counter() if phase_log is not None else 0.0
+            if span_log is not None:
+                t = _mark(span_log, "decode.dispatch", t)
             nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
             if span_log is not None:
-                span_log.append(("decode_step", tw, time.time()))
-            if phase_log is not None:
-                # dispatch ends when the async decode call returns; the
-                # argmax readback above forced the device sync
-                phase_log.append((t_disp - ts, time.perf_counter() - t_disp))
+                t = _mark(span_log, "decode.sync", t)
             if hook is not None:
                 hook.fire()   # inside the timed sample, like harness.measure
+                if span_log is not None:
+                    t = time.time()
             dt = time.perf_counter() - ts
             self.steps += 1
             step += 1
@@ -362,10 +425,12 @@ class ServeEngine:
                     req.t_done = time.perf_counter()
                     active -= 1
                     done_count += 1
+            if span_log is not None:
+                _mark(span_log, "decode.commit", t)
         wall = time.perf_counter() - t0
         ab = self._admit_batches
         # fleet metrics: folded ONCE per replay (never per decode step) —
-        # admission control-path counters + the end-of-replay KV fill
+        # admission control-path counters
         from repro.fleet.metrics import registry as metrics_registry
         reg = metrics_registry()
         reg.inc("serve_admit_waves_total", waves)
@@ -373,9 +438,6 @@ class ServeEngine:
         reg.inc("serve_bucket_compiles_total",
                 len(self._admit_shapes) - shapes0)
         reg.inc("serve_decode_steps_total", self.steps)
-        reg.set_gauge("serve_kv_occupancy",
-                      float(np.mean(self.slot_pos)) / self.max_len
-                      if self.max_len else 0.0)
         return {"requests": total, "decode_steps": self.steps,
                 "tokens": tokens_out, "wall_s": wall,
                 "tok_per_s": tokens_out / wall if wall else 0.0,

@@ -45,25 +45,27 @@ def _attn_ffn_block(p, x, cfg, *, kind: str, positions, cache, use_moe: bool,
     if kind == "prefix":
         mask = "prefix"
     window = cfg.local_window if mask == "local" else 0
-    h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-    if cfg.use_mla:
-        h, new_c = mla.mla_attention(p["attn"], h, cfg, positions=positions,
-                                     cache=cache, seq_lens=seq_lens)
-    else:
-        h, new_c = L.gqa_attention(
-            p["attn"], h, cfg, mask_type=mask, window=window,
-            prefix_len=cfg.n_prefix if kind == "prefix" else 0,
-            positions=positions, cache=cache, seq_lens=seq_lens)
-    x = x + h
-    h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-    if use_moe:
-        # serving admission (seq_lens set): one dispatch group per row, so
-        # expert capacity — a per-group resource — can't couple co-admitted
-        # requests' routing (see moe_ffn)
-        h = moe.moe_ffn(p["mlp"], h, cfg, row_groups=seq_lens is not None)
-    else:
-        h = L.ffn(p["mlp"], h, cfg)
-    x = x + h
+    with jax.named_scope("attn"):
+        h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+        if cfg.use_mla:
+            h, new_c = mla.mla_attention(p["attn"], h, cfg, positions=positions,
+                                         cache=cache, seq_lens=seq_lens)
+        else:
+            h, new_c = L.gqa_attention(
+                p["attn"], h, cfg, mask_type=mask, window=window,
+                prefix_len=cfg.n_prefix if kind == "prefix" else 0,
+                positions=positions, cache=cache, seq_lens=seq_lens)
+        x = x + h
+    with jax.named_scope("mlp"):
+        h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+        if use_moe:
+            # serving admission (seq_lens set): one dispatch group per row, so
+            # expert capacity — a per-group resource — can't couple co-admitted
+            # requests' routing (see moe_ffn)
+            h = moe.moe_ffn(p["mlp"], h, cfg, row_groups=seq_lens is not None)
+        else:
+            h = L.ffn(p["mlp"], h, cfg)
+        x = x + h
     return logical(x, ("act_batch", "act_seq", "act_embed")), new_c
 
 
@@ -89,10 +91,12 @@ def _rec_block_defs(cfg, lp):
 
 
 def _mamba_block(p, x, cfg, *, cache, seq_lens=None):
-    h = L.rms_norm(x, p["ln"], cfg.norm_eps)
-    h, new_c = ssm.mamba2_block(p["mix"], h, cfg, cache=cache,
-                                seq_lens=seq_lens)
-    return logical(x + h, ("act_batch", "act_seq", "act_embed")), new_c
+    with jax.named_scope("ssm"):
+        h = L.rms_norm(x, p["ln"], cfg.norm_eps)
+        h, new_c = ssm.mamba2_block(p["mix"], h, cfg, cache=cache,
+                                    seq_lens=seq_lens)
+        x = x + h
+    return logical(x, ("act_batch", "act_seq", "act_embed")), new_c
 
 
 # ---------------------------------------------------------------------------
@@ -192,6 +196,7 @@ class Model:
 
     # ---------------- embedding / head ----------------
 
+    @jax.named_scope("embed")
     def _embed(self, params, tokens, positions=None):
         cfg = self.cfg
         x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.compute_dtype)
@@ -202,6 +207,7 @@ class Model:
             x = x + jnp.take(params["pos_embed"], pos, axis=0).astype(cfg.compute_dtype)
         return logical(x, ("act_batch", "act_seq", "act_embed"))
 
+    @jax.named_scope("head")
     def _head(self, params, x):
         cfg = self.cfg
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)

@@ -56,9 +56,10 @@ class Timeline:
     def from_phase_log(cls, log: Sequence[Tuple[float, float]], *,
                        kind: str = "step", wall_s: float = 0.0,
                        memory: Optional[Dict[str, int]] = None) -> "Timeline":
-        """Build from a harness ``phase_log`` — (dispatch_s, device_s)
-        tuples in **seconds** as appended by ``harness.measure`` /
-        ``ServeEngine.run``.  ``wall_s`` (serve) is the measured replay
+        """Build from a ``phase_log`` — (dispatch_s, device_s) tuples in
+        **seconds**, as ``harness.measure`` appends them or
+        ``launch.serve.decode_phase_log`` derives them from the serve
+        engine's spans.  ``wall_s`` (serve) is the measured replay
         wall; any part of it not inside the logged steps becomes idle."""
         samples = [PhaseSample(d * 1e6, v * 1e6) for d, v in log]
         idle = 0.0

@@ -525,10 +525,11 @@ class BenchmarkRunner:
         yields a latency-vs-load curve.
 
         ``profile=True`` records a per-decode-step phase timeline during
-        the measured replay and attributes it over the decode step's HLO
-        op classes; replay wall time outside decode steps (admission,
-        prefill, queue management) shows up as the profile's idle share."""
-        from repro.launch.serve import summarize_metrics
+        the measured replay (from the engine's ``decode.*`` spans) and
+        attributes it over the decode step's HLO op classes; replay wall
+        time outside decode steps (admission, prefill, queue management)
+        shows up as the profile's idle share."""
+        from repro.launch.serve import decode_phase_log, summarize_metrics
         from repro.runner.loadgen import scale_arrivals, shard_requests
         from repro.runner.traces import capture_spec
         t0 = time.perf_counter()
@@ -578,12 +579,12 @@ class BenchmarkRunner:
                         tc = time.perf_counter()
                         engine.run(reqs)
                         compile_us = (time.perf_counter() - tc) * 1e6
-                phase_log: Optional[List[Tuple[float, float]]] = \
-                    [] if profile else None
-                span_log: Optional[list] = [] if tr.enabled else None
+                # the engine's phase spans feed both the trace and the
+                # profile's dispatch/device split
+                logged = tr.enabled or profile
+                span_log: Optional[list] = [] if logged else None
                 with tr.span("measure", kind="phase") as ms:
-                    out = engine.run(reqs, hook=hook, phase_log=phase_log,
-                                     span_log=span_log)
+                    out = engine.run(reqs, hook=hook, span_log=span_log)
                 self._add_serve_spans(tr, ms, span_log)
                 if out["admit_new_shapes"]:
                     # this replay's queue dynamics reached prefill bucket
@@ -594,13 +595,10 @@ class BenchmarkRunner:
                     # the rerun is shape-complete because the replay is
                     # deterministic
                     compile_us += out["wall_s"] * 1e6
-                    phase_log = [] if profile else None
-                    span_log = [] if tr.enabled else None
+                    span_log = [] if logged else None
                     with tr.span("measure", kind="phase",
                                  remeasure=True) as ms:
-                        out = engine.run(reqs, hook=hook,
-                                         phase_log=phase_log,
-                                         span_log=span_log)
+                        out = engine.run(reqs, hook=hook, span_log=span_log)
                     self._add_serve_spans(tr, ms, span_log)
                 sx = summarize_metrics(out)
                 plens = sorted(len(r.prompt) for r in reqs)
@@ -620,7 +618,7 @@ class BenchmarkRunner:
                 if profile:
                     with tr.span("attribute", kind="phase"):
                         sx.update(self._profile_extra(
-                            ("serve-cost",) + key, phase_log,
+                            ("serve-cost",) + key, decode_phase_log(span_log),
                             engine.lowered_decode, kind="decode_step",
                             wall_s=out["wall_s"]))
                 lats = out["tok_lat_s"] or out["ttft_s"]
@@ -652,27 +650,27 @@ class BenchmarkRunner:
     @staticmethod
     def _add_serve_spans(tr: Tracer, parent: Any, span_log: Optional[list],
                          cap: int = 64) -> None:
-        """Attach the engine's admit-wave / decode-step wall intervals as
-        children of the serve cell's measure span.  Decode steps beyond
-        *cap* are elided (count + total time noted on the parent) so a
-        long replay doesn't bloat the trace."""
-        if not span_log:
+        """Attach the engine's phase spans (``admit.*``, ``decode.*``) as
+        children of the serve cell's measure span.  The phases of decode
+        steps beyond *cap* are elided (step count + their total time noted
+        on the parent) so a long replay doesn't bloat the trace."""
+        from repro.launch.serve import DECODE_PHASES
+        if not tr.enabled or not span_log:
             return
-        shown = dropped = 0
+        steps = 0
         dropped_s = 0.0
         for ev in span_log:
             name, tw0, tw1 = ev[0], ev[1], ev[2]
-            attrs = ev[3] if len(ev) > 3 and isinstance(ev[3], dict) else {}
-            if name == "decode_step":
-                if shown >= cap:
-                    dropped += 1
-                    dropped_s += tw1 - tw0
-                    continue
-                shown += 1
+            attrs = ev[3] if len(ev) > 3 else {}
+            if name == DECODE_PHASES[0]:
+                steps += 1
+            if name in DECODE_PHASES and steps > cap:
+                dropped_s += tw1 - tw0
+                continue
             tr.add(name, ts=tw0, dur_s=tw1 - tw0, parent=parent,
                    kind="engine", **attrs)
-        if dropped:
-            parent.set(decode_steps_dropped=dropped,
+        if steps > cap:
+            parent.set(decode_steps_dropped=steps - cap,
                        decode_steps_dropped_s=round(dropped_s, 6))
 
     def select(self, matrix: ScenarioMatrix) -> List[Scenario]:

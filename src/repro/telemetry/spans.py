@@ -9,7 +9,7 @@ span hierarchy is::
         dispatch:<scenario>         (pool / cluster dispatch slot)
           cell:<scenario>           (worker lane, stitched by trace ctx)
             build / compile / warm / measure / attribute   (phases)
-              admit_wave / decode_step                     (serve only)
+              admit.* / decode.* engine phases             (serve only)
 
 Design constraints:
 
