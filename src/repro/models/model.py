@@ -228,7 +228,37 @@ class Model:
         return jax.checkpoint(fn, policy=policy)
 
     def _scan_stack(self, body, x, stacked_params, stacked_cache, extras=()):
-        """Scan ``body(p_i, x, c_i) -> (x, c_i')`` over the layer axis."""
+        """Scan ``body(p_i, x, c_i) -> (x, c_i')`` over the layer axis.
+
+        At decode (one position per row) the stacked cache rides in the
+        scan's carry and each layer writes its new slice back in place, at
+        its own index.  Read as ``xs`` and rebuilt as ``ys``, the donated
+        cache buffer would serve both, and XLA would copy the whole stack
+        before the loop to keep the reads intact.  A longer input keeps the
+        ``xs``/``ys`` form: prefill fills a cache made inside the same
+        program, so there is no donated buffer to copy, and the carry would
+        only add reads of it."""
+        if stacked_cache is not None and x.shape[1] == 1:
+            def step(carry, inp):
+                x, stack = carry
+                p_i, i = inp
+                c_i = jax.tree.map(
+                    lambda c: jax.lax.dynamic_index_in_dim(c, i, keepdims=False), stack)
+                y, c_new = body(p_i, x, c_i, *extras)
+                # a leaf the layer hands back as it read it (encdec's cross
+                # K/V) is left where it is, not written back
+                stack = jax.tree.map(
+                    lambda c, old, new: c if new is old else
+                    jax.lax.dynamic_update_index_in_dim(c, new.astype(c.dtype), i, 0),
+                    stack, c_i, c_new)
+                return (y, stack), None
+
+            n = jax.tree.leaves(stacked_params)[0].shape[0]
+            (x, stacked_cache), _ = jax.lax.scan(
+                self._maybe_remat(step), (x, stacked_cache),
+                (stacked_params, jnp.arange(n)))
+            return x, stacked_cache
+
         has_cache = stacked_cache is not None
 
         def f(carry, inp):

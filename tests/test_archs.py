@@ -11,6 +11,12 @@ from repro.models import build_model
 
 ALL_ARCHS = list(list_archs())
 
+#: every registered arch, plus a hybrid stack deep enough to leave a
+#: ``tail`` of recurrent layers after its groups (its reduced depth has none)
+DECODE_CASES = [pytest.param(a, {}, id=a) for a in ALL_ARCHS] + [
+    pytest.param("recurrentgemma-9b", {"n_layers": 7},
+                 id="recurrentgemma-9b-tail")]
+
 
 def _batch_for(cfg, B, S, key=2):
     toks = jax.random.randint(jax.random.key(1), (B, S), 0, cfg.vocab)
@@ -53,9 +59,9 @@ def test_smoke_train_step_no_nans(arch):
     assert delta > 0
 
 
-@pytest.mark.parametrize("arch", ALL_ARCHS)
-def test_prefill_decode_matches_forward(arch):
-    cfg = get_arch(arch).reduced()
+@pytest.mark.parametrize("arch,overrides", DECODE_CASES)
+def test_prefill_decode_matches_forward(arch, overrides):
+    cfg = get_arch(arch).reduced(**overrides)
     model = build_model(cfg)
     params = model.init(jax.random.key(0))
     B, S = 2, 48

@@ -13,6 +13,7 @@ loads the TPU library.  Keep these cases in this one file.
 """
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -110,3 +111,34 @@ def test_gemma_2b_full_width_decode_fits_one_chip(one_chip):
     mem = c.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < V5E_HBM_BYTES / 2, used
+
+
+@pytest.mark.parametrize("arch,layers,slots", [
+    ("internlm2-20b", 6, 32),   # dense: stacked K and V caches
+    ("mamba2-2.7b", 64, 16),    # ssm: stacked f32 SSM state and conv state
+])
+def test_decode_step_updates_the_donated_cache_in_place(one_chip, arch,
+                                                        layers, slots):
+    """The serve engine donates the cache to its decode step (bf16 weights,
+    max_len 1024, as the chip benchmark serves them).  No copy in the
+    compiled program, in any computation and while bodies included, has
+    the shape of a stacked cache leaf: the layer scan updates the donated
+    buffer in place instead of copying it whole before the loop.  (An
+    asynchronous ``copy-start`` moves a buffer between memory spaces, a
+    prefetch; it is not counted.)"""
+    cfg = dataclasses.replace(get_arch(arch), n_layers=layers,
+                              param_dtype=jnp.bfloat16)
+    model = build_model(cfg)
+
+    def placed(tree):
+        return jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+
+    cache = placed(abstract_tree(model.cache_defs(slots, 1024)))
+    text = jax.jit(model.decode_step, donate_argnums=(2,)).lower(
+        placed(model.abstract_params()), _sds(one_chip, (slots, 1), jnp.int32),
+        cache).compile().as_text()
+    stacked = {",".join(map(str, leaf.shape)) for leaf in jax.tree.leaves(cache)}
+    copies = [m.group(0) for m in re.finditer(
+        r"%\S+ = \w+\[([\d,]*)\]\S* copy\(", text)
+        if m.group(1) in stacked]
+    assert not copies, copies
