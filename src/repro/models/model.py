@@ -90,11 +90,11 @@ def _rec_block_defs(cfg, lp):
             "ln2": _norm_def(cfg, lp), "mlp": L.ffn_defs(cfg, None, lp)}
 
 
-def _mamba_block(p, x, cfg, *, cache, seq_lens=None):
+def _mamba_block(p, x, cfg, *, cache, seq_lens=None, layer=None):
     with jax.named_scope("ssm"):
         h = L.rms_norm(x, p["ln"], cfg.norm_eps)
         h, new_c = ssm.mamba2_block(p["mix"], h, cfg, cache=cache,
-                                    seq_lens=seq_lens)
+                                    seq_lens=seq_lens, layer=layer)
         x = x + h
     return logical(x, ("act_batch", "act_seq", "act_embed")), new_c
 
@@ -227,7 +227,8 @@ class Model:
             policy = jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims
         return jax.checkpoint(fn, policy=policy)
 
-    def _scan_stack(self, body, x, stacked_params, stacked_cache, extras=()):
+    def _scan_stack(self, body, x, stacked_params, stacked_cache, extras=(),
+                    in_place=frozenset()):
         """Scan ``body(p_i, x, c_i) -> (x, c_i')`` over the layer axis.
 
         At decode (one position per row) the stacked cache rides in the
@@ -237,18 +238,30 @@ class Model:
         before the loop to keep the reads intact.  A longer input keeps the
         ``xs``/``ys`` form: prefill fills a cache made inside the same
         program, so there is no donated buffer to copy, and the carry would
-        only add reads of it."""
+        only add reads of it.
+
+        ``in_place`` names cache leaves that the body steps in place
+        itself: at decode it gets them whole, with the layer index as
+        ``layer=``, and hands them back whole.  A kernel given the whole
+        stack writes only the layer's slice; given a slice, it would read
+        a copy."""
         if stacked_cache is not None and x.shape[1] == 1:
+            def whole(path):
+                return getattr(path[-1], "key", None) in in_place
+
             def step(carry, inp):
                 x, stack = carry
                 p_i, i = inp
-                c_i = jax.tree.map(
-                    lambda c: jax.lax.dynamic_index_in_dim(c, i, keepdims=False), stack)
-                y, c_new = body(p_i, x, c_i, *extras)
+                c_i = jax.tree_util.tree_map_with_path(
+                    lambda path, c: c if whole(path) else
+                    jax.lax.dynamic_index_in_dim(c, i, keepdims=False), stack)
+                kw = {"layer": i} if in_place else {}
+                y, c_new = body(p_i, x, c_i, *extras, **kw)
                 # a leaf the layer hands back as it read it (encdec's cross
                 # K/V) is left where it is, not written back
-                stack = jax.tree.map(
-                    lambda c, old, new: c if new is old else
+                stack = jax.tree_util.tree_map_with_path(
+                    lambda path, c, old, new: c if new is old else
+                    new if whole(path) else
                     jax.lax.dynamic_update_index_in_dim(c, new.astype(c.dtype), i, 0),
                     stack, c_i, c_new)
                 return (y, stack), None
@@ -323,10 +336,12 @@ class Model:
                     new_cache["blocks"] = c_new
 
         elif fam == "ssm":
-            def body(p_i, x, c_i):
-                return _mamba_block(p_i, x, cfg, cache=c_i, seq_lens=seq_lens)
+            def body(p_i, x, c_i, layer=None):
+                return _mamba_block(p_i, x, cfg, cache=c_i, seq_lens=seq_lens,
+                                    layer=layer)
             c = cache.get("blocks") if cache else None
-            x, c_new = self._scan_stack(body, x, params["blocks"], c)
+            x, c_new = self._scan_stack(body, x, params["blocks"], c,
+                                        in_place={"ssm"})
             if cache is not None:
                 new_cache["blocks"] = c_new
 

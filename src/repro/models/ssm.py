@@ -4,16 +4,19 @@
 Pallas kernel in ``repro.kernels.ssd``): quadratic attention-like math
 *within* MXU-aligned chunks, a linear recurrence *across* chunks, carried by
 ``lax.scan``.  ``ssd_sequential`` is the slow per-token reference used in
-tests.  ``ssd_step`` is the O(1)-per-token decode update.
+tests.  ``ssd_step`` is the O(1)-per-token decode update, and
+``ssd_step_stacked`` applies it to one layer of the stacked decode state.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.distributed import logical
+from repro.kernels.ssd.decode import ssd_decode
 from repro.models.layers import ParamDef, causal_conv1d, rms_norm
 
 
@@ -112,6 +115,23 @@ def ssd_step(state, xt, dtt, A, bt, ct):
     return y.astype(xt.dtype), state
 
 
+def ssd_step_stacked(stack, layer, xt, dtt, A, bt, ct):
+    """``ssd_step`` on layer ``layer`` of the stacked state (L, B, H, P, N).
+
+    Returns y and the stack with that layer's slice stepped in place.
+    Lowered for a TPU, one Pallas kernel (``repro.kernels.ssd.decode``)
+    reads and writes each state tile once; on any other platform the
+    slice goes through ``ssd_step`` and is written back."""
+    def xla(stack, layer, *args):
+        y, h = ssd_step(jax.lax.dynamic_index_in_dim(stack, layer, keepdims=False),
+                        *args)
+        return y, jax.lax.dynamic_update_index_in_dim(stack, h, layer, 0)
+
+    return jax.lax.platform_dependent(stack, layer, xt, dtt, A, bt, ct,
+                                      tpu=partial(ssd_decode, interpret=False),
+                                      default=xla)
+
+
 # ---------------------------------------------------------------------------
 # Mamba-2 block
 # ---------------------------------------------------------------------------
@@ -158,8 +178,13 @@ def _split_in_proj(zxbcdt, cfg):
 
 
 def mamba2_block(p: dict, u: jax.Array, cfg, cache: Optional[dict] = None,
-                 seq_lens: Optional[jax.Array] = None):
+                 seq_lens: Optional[jax.Array] = None,
+                 layer: Optional[jax.Array] = None):
     """u (B, S, E) -> (y, new_cache).
+
+    At decode (S == 1 with a cache) ``cache["ssm"]`` is the whole stacked
+    state and ``layer`` this layer's index in it; the new cache holds the
+    whole stack back (``Model._scan_stack``'s ``in_place``).
 
     ``seq_lens`` (B,) marks each row's valid prefix under right-padded
     batched prefill: pad steps become identity SSD updates (dt=0 -> decay 1,
@@ -191,7 +216,8 @@ def mamba2_block(p: dict, u: jax.Array, cfg, cache: Optional[dict] = None,
 
     new_cache = None
     if cache is not None and S == 1:
-        y, new_state = ssd_step(cache["ssm"], x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0])
+        y, new_state = ssd_step_stacked(cache["ssm"], layer, x[:, 0], dt[:, 0], A,
+                                        Bm[:, 0], Cm[:, 0])
         y = y[:, None]
         new_cache = {"conv": new_conv, "ssm": new_state, "len": cache["len"] + 1}
     else:

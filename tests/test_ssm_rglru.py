@@ -1,12 +1,15 @@
 """SSD + RG-LRU model-layer invariants: chunked == sequential, decode-step
 chain == full scan (the cache-correctness property for SSM/hybrid serving)."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.ssd.decode import ssd_decode
 from repro.models.rglru import rglru_scan, rglru_step
-from repro.models.ssm import ssd_chunked, ssd_sequential, ssd_step
+from repro.models.ssm import ssd_chunked, ssd_sequential, ssd_step, ssd_step_stacked
 
 
 def _ssd_inputs(B=2, S=64, H=3, P=16, N=32, seed=0):
@@ -49,6 +52,41 @@ def test_ssd_carried_state_across_segments():
     np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 1)),
                                np.asarray(y_full), atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(h2), np.asarray(h_full), atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("H", [16, 80])
+@pytest.mark.parametrize("branch", ["kernel", "xla"])
+def test_ssd_decode_steps_one_layer_of_the_stack(branch, H, layer):
+    """One decode step on one layer of a stacked f32 state, at mamba2's
+    P=64, N=128 with bf16 inputs (80 heads is mamba2-2.7b's count): the
+    Pallas kernel (interpreted here) and the XLA branch that
+    ``ssd_step_stacked`` takes off the TPU both match ``ssd_step`` on that
+    layer's slice, and leave every other layer's slice bit for bit."""
+    L, B, P, N = 3, 2, 64, 128
+    k = jax.random.split(jax.random.key(7), 6)
+    stack = jax.random.normal(k[0], (L, B, H, P, N), jnp.float32)
+    x = jax.random.normal(k[1], (B, H, P)).astype(jnp.bfloat16)
+    dt = jax.nn.softplus(jax.random.normal(k[2], (B, H)))
+    A = -jnp.exp(jax.random.normal(k[3], (H,)) * 0.3)
+    bm = jax.random.normal(k[4], (B, N)).astype(jnp.bfloat16)
+    cm = jax.random.normal(k[5], (B, N)).astype(jnp.bfloat16)
+    if branch == "xla":
+        step = jax.jit(ssd_step_stacked)
+    else:
+        step = jax.jit(partial(ssd_decode, interpret=True))
+    y, new = step(stack, layer, x, dt, A, bm, cm)
+    y_ref, h_ref = ssd_step(stack[layer], x, dt, A, bm, cm)
+    assert y.dtype == jnp.bfloat16 and new.dtype == jnp.float32
+    # the same f32 products and sums, up to their order and fusion
+    h_ref = np.asarray(h_ref)
+    assert np.max(np.abs(np.asarray(new[layer]) - h_ref)) <= 1e-5 * np.max(np.abs(h_ref))
+    # y is rounded to bf16 from f32 sums that may differ in order: each side
+    # rounds by at most 2^-8 of the value
+    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y_ref, np.float32),
+                               rtol=2 ** -7, atol=1e-6)
+    for other in set(range(L)) - {layer}:
+        np.testing.assert_array_equal(np.asarray(new[other]), np.asarray(stack[other]))
 
 
 def test_rglru_scan_matches_step_chain():

@@ -113,32 +113,72 @@ def test_gemma_2b_full_width_decode_fits_one_chip(one_chip):
     assert used < V5E_HBM_BYTES / 2, used
 
 
-@pytest.mark.parametrize("arch,layers,slots", [
-    ("internlm2-20b", 6, 32),   # dense: stacked K and V caches
-    ("mamba2-2.7b", 64, 16),    # ssm: stacked f32 SSM state and conv state
-])
-def test_decode_step_updates_the_donated_cache_in_place(one_chip, arch,
-                                                        layers, slots):
-    """The serve engine donates the cache to its decode step (bf16 weights,
-    max_len 1024, as the chip benchmark serves them).  No copy in the
-    compiled program, in any computation and while bodies included, has
-    the shape of a stacked cache leaf: the layer scan updates the donated
-    buffer in place instead of copying it whole before the loop.  (An
-    asynchronous ``copy-start`` moves a buffer between memory spaces, a
-    prefetch; it is not counted.)"""
+def _decode_step(sharding, arch, layers, slots):
+    """The serve engine's decode step as the chip benchmark serves it (bf16
+    weights, max_len 1024, the cache donated), compiled for ``sharding``:
+    (model, abstract cache, HLO text)."""
     cfg = dataclasses.replace(get_arch(arch), n_layers=layers,
                               param_dtype=jnp.bfloat16)
     model = build_model(cfg)
 
     def placed(tree):
-        return jax.tree.map(lambda s: _sds(one_chip, s.shape, s.dtype), tree)
+        return jax.tree.map(lambda s: _sds(sharding, s.shape, s.dtype), tree)
 
     cache = placed(abstract_tree(model.cache_defs(slots, 1024)))
     text = jax.jit(model.decode_step, donate_argnums=(2,)).lower(
-        placed(model.abstract_params()), _sds(one_chip, (slots, 1), jnp.int32),
+        placed(model.abstract_params()), _sds(sharding, (slots, 1), jnp.int32),
         cache).compile().as_text()
+    return model, cache, text
+
+
+DECODE_CASES = pytest.mark.parametrize("arch,layers,slots", [
+    ("internlm2-20b", 6, 32),   # dense: stacked K and V caches
+    ("mamba2-2.7b", 64, 16),    # ssm: stacked f32 SSM state and conv state
+])
+
+
+@DECODE_CASES
+def test_decode_step_updates_the_donated_cache_in_place(one_chip, arch,
+                                                        layers, slots):
+    """The serve engine donates the cache to its decode step.  No copy in
+    the compiled program, in any computation and while bodies included,
+    has the shape of a stacked cache leaf: the layer scan updates the
+    donated buffer in place instead of copying it whole before the loop.
+    (An asynchronous ``copy-start`` moves a buffer between memory spaces,
+    a prefetch; it is not counted.)"""
+    _, cache, text = _decode_step(one_chip, arch, layers, slots)
     stacked = {",".join(map(str, leaf.shape)) for leaf in jax.tree.leaves(cache)}
     copies = [m.group(0) for m in re.finditer(
         r"%\S+ = \w+\[([\d,]*)\]\S* copy\(", text)
         if m.group(1) in stacked]
     assert not copies, copies
+
+
+#: one HLO instruction: name, result shape, opcode, operands
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (.+?) ([\w-]+)\((.*?)\)(?:,|$)", re.M)
+
+
+@DECODE_CASES
+def test_decode_step_steps_the_ssm_state_in_one_kernel(one_chip, arch,
+                                                       layers, slots):
+    """mamba2's compiled decode step steps the stacked f32 SSM state in the
+    ``ssd_decode`` Pallas kernel, and no fusion reads the stack: XLA's
+    update and its separate ``y`` pass, which read the state twice, are
+    gone.  The dense family's decode step holds no such kernel."""
+    model, cache, text = _decode_step(one_chip, arch, layers, slots)
+    shapes, readers = {}, []
+    for name, shape, opcode, operands in _INSTRUCTION.findall(text):
+        shapes[name] = shape
+        readers.append((name, opcode, [o.strip().lstrip("%") for o in operands.split(",")]))
+    kernels = [name for name, opcode, _ in readers
+               if opcode == "custom-call" and name.startswith("ssd_decode")]
+    if model.cfg.family != "ssm":
+        assert not kernels
+        return
+    state = "f32[%s]" % ",".join(map(str, cache["blocks"]["ssm"].shape))
+    assert state == "f32[%d,%d,80,64,128]" % (layers, slots)
+    reads = [(name, opcode) for name, opcode, operands in readers
+             if any(shapes.get(o, "").startswith(state + "{") for o in operands)]
+    assert kernels and {name for name, _ in reads if name in kernels}, reads
+    assert not [r for r in reads if r[1] == "fusion"], reads
