@@ -11,8 +11,8 @@ domain.  Two provenances, labeled per row (``source=measured|analytic``):
   cells shared with fig5/roofline cost one subprocess total.
 
 Everything executes through the shared ``BenchmarkRunner`` — the measured
-sweep honors ``--jobs``/``--isolate``/session filters like every other
-table, and the dry-run path reuses the store-level cell cache."""
+sweep honors the session filters like every other table, and the dry-run
+path reuses the store-level cell cache."""
 from __future__ import annotations
 
 import json
@@ -55,7 +55,6 @@ def _emit_rows(rows, prefix: str) -> None:
 def main(fast: bool = False, runner=None) -> None:
     runner = runner or make_runner()
     # measured rows first: a profiled sweep through the shared runner
-    # (sharded under --jobs exactly like any other matrix)
     measured = runner.run_matrix(_measured_matrix(fast), profile=True)
     rows = measured_breakdown_rows(measured)
     # analytic fallback for the production shapes we can only compile

@@ -22,7 +22,7 @@ schema consumed by ``repro.runner.loadgen.auto_slots`` (the knee-driven
 ``extra`` is appended to the shared ResultStore so CI baselines can
 track the knee like any other scalar.
 
-    PYTHONPATH=src python -m benchmarks.loadgen_curve [--fast] [--jobs N]
+    PYTHONPATH=src python -m benchmarks.loadgen_curve [--fast]
 """
 from __future__ import annotations
 
@@ -157,11 +157,5 @@ if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
-    ap.add_argument("--jobs", type=int, default=0,
-                    help="shard the loadgen sweep across N workers")
     args = ap.parse_args()
-    r = make_runner(jobs=args.jobs)
-    try:
-        main(fast=args.fast, runner=r)
-    finally:
-        r.close()
+    main(fast=args.fast, runner=make_runner())

@@ -3,13 +3,13 @@ paper's use case 1: profile the benchmarks, find the optimization
 targets).
 
 Sweeps a step matrix + a serve cell through the shared BenchmarkRunner
-with ``profile=True`` (sharded under ``--jobs`` like every table), runs
+with ``profile=True``, runs
 the rule-based detectors (``repro.profiler.detectors``) over the profiled
 RunResults, and emits a ranked findings report — CSV rows per finding,
 a human table on stderr-safe comment lines, and the full JSON (records'
 prof summaries + findings + tallies) in ``results/profile_report.json``.
 
-    PYTHONPATH=src python -m benchmarks.profile_report [--fast] [--jobs N]
+    PYTHONPATH=src python -m benchmarks.profile_report [--fast]
 
 ``--drain-queue`` closes the detect -> tune loop from the other side:
 instead of profiling, it turns the queued jobs a previous report wrote
@@ -55,8 +55,7 @@ def _prof_summary(rec: dict) -> dict:
             if k.startswith("prof_") and k != "prof_timeline"}
     return {"name": rec["name"], "status": rec["status"],
             "median_us": rec.get("median_us"),
-            "compile_us": rec.get("compile_us"),
-            "shard": extra.get("shard"), **keep}
+            "compile_us": rec.get("compile_us"), **keep}
 
 
 def drain_queue(runner=None, queue_path=None) -> dict:
@@ -126,17 +125,12 @@ if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
-    ap.add_argument("--jobs", type=int, default=0,
-                    help="shard the profiled sweep across N workers")
     ap.add_argument("--drain-queue", action="store_true",
                     help="sweep results/tuning_queue.json jobs instead of "
                          "profiling, then empty the queue")
     args = ap.parse_args()
-    r = make_runner(jobs=args.jobs)
-    try:
-        if args.drain_queue:
-            drain_queue(runner=r)
-        else:
-            main(fast=args.fast, runner=r)
-    finally:
-        r.close()
+    r = make_runner()
+    if args.drain_queue:
+        drain_queue(runner=r)
+    else:
+        main(fast=args.fast, runner=r)

@@ -2,13 +2,12 @@
 executed through the unified ``repro.runner.BenchmarkRunner``.
 
     PYTHONPATH=src python -m benchmarks.run [--fast] [--only NAME]
-        [--filter RE ...] [--exclude RE ...] [--isolate] [--jobs N]
-        [--cluster local:N|HOST:PORT] [--profile] [--list]
+        [--filter RE ...] [--exclude RE ...] [--profile] [--list]
         [--trace-out PATH]
 
 ``--list`` prints the scenario names each matrix-driven table would run
 (after filter/exclude/skip selection) and exits without executing —
-cheap debugging for sharded sweeps.
+cheap debugging for large sweeps.
 
 One ``BenchmarkRunner`` + ``ResultStore`` (``results/store``) is shared by
 every table: arch builds, compiled executables, and dry-run cells are
@@ -18,13 +17,9 @@ JSONL run log with a latest-pointer for ``scripts/report_tables.py``.
 
 ``--filter`` / ``--exclude`` are regexes over scenario names
 ("arch/task/bN/sN/dtype/mode"), applied to the measured-suite tables —
-the torchbench driver's model-selection semantics.  ``--isolate`` runs
-each scenario in its own subprocess (fault containment for crashy cells);
-``--jobs N`` shards every ``run_matrix`` sweep across N persistent worker
-subprocesses (see ``repro/runner/pool.py``); ``--cluster local:N`` (or
-``--cluster HOST:PORT`` with workers launched elsewhere via ``python -m
-repro.runner.worker --connect HOST:PORT``) dispatches every sweep across
-socket-connected cluster workers instead (see ``repro/runner/cluster/``).
+the torchbench driver's model-selection semantics.  Every cell runs in
+this process (a chip belongs to one process).  The chip measurement is
+``python3 chipbench/run.py`` (``BENCHMARK.json``), not this harness.
 
 Prints ``name,us_per_call,derived`` CSV rows (benchmarks.common.emit).
 """
@@ -47,15 +42,6 @@ def main(argv=None) -> int:
                     help="regex over scenario names; keep matches")
     ap.add_argument("--exclude", action="append", default=[],
                     help="regex over scenario names; drop matches")
-    ap.add_argument("--isolate", action="store_true",
-                    help="one subprocess per scenario (fault containment)")
-    ap.add_argument("--jobs", type=int, default=0,
-                    help="shard matrix sweeps across N worker subprocesses")
-    ap.add_argument("--cluster", default="",
-                    help="dispatch matrix sweeps across cluster workers: "
-                         "'local:N' spawns N localhost workers, 'HOST:PORT' "
-                         "binds the coordinator there for external "
-                         "worker --connect processes")
     ap.add_argument("--profile", action="store_true",
                     help="measured profiling on every matrix cell: phase "
                          "timelines + op-class attribution under "
@@ -64,8 +50,8 @@ def main(argv=None) -> int:
                     help="recompile cached dry-run cells (after config/model changes)")
     ap.add_argument("--trace-out", default="",
                     help="trace every run_matrix call and write one "
-                         "stitched Chrome trace-event JSON (Perfetto-"
-                         "loadable) here; also prints a text flame "
+                         "Chrome trace-event JSON (Perfetto-loadable) "
+                         "here; also prints a text flame "
                          "summary (src/repro/telemetry/)")
     args = ap.parse_args(argv)
     from repro.launch.compile_cache import enable_compile_cache
@@ -76,8 +62,7 @@ def main(argv=None) -> int:
                             profile_report, roofline, runner_bench,
                             serve_latency, table1_suite, table45_ci)
     from benchmarks.common import make_runner
-    runner = make_runner(isolate=args.isolate, jobs=args.jobs,
-                         cluster=args.cluster, profile=args.profile)
+    runner = make_runner(profile=args.profile)
     runner.default_filter = tuple(args.filter)
     runner.default_exclude = tuple(args.exclude)
     runner.dryrun_refresh = args.refresh
@@ -99,7 +84,7 @@ def main(argv=None) -> int:
         "history_report": history_report.main,     # provenance trajectories
     }
     if args.list:
-        # sharded-sweep debugging: show exactly which cells each table's
+        # sweep debugging: show exactly which cells each table's
         # matrices select under the session --filter/--exclude, zero
         # execution.  Tables without a scenario_matrices hook (dry-run /
         # single-probe tables) are reported as such.
@@ -116,20 +101,17 @@ def main(argv=None) -> int:
                     print(f"{name} {sc.name}")
         return 0
     failed = 0
-    try:
-        for name, fn in tables.items():
-            if args.only and name != args.only:
-                continue
-            print(f"# === {name} ===", flush=True)
-            t0 = time.time()
-            try:
-                fn(fast=args.fast, runner=runner)
-                print(f"# {name} done in {time.time()-t0:.1f}s", flush=True)
-            except Exception:
-                failed += 1
-                print(f"# {name} FAILED:\n{traceback.format_exc()}", file=sys.stderr, flush=True)
-    finally:
-        runner.close()
+    for name, fn in tables.items():
+        if args.only and name != args.only:
+            continue
+        print(f"# === {name} ===", flush=True)
+        t0 = time.time()
+        try:
+            fn(fast=args.fast, runner=runner)
+            print(f"# {name} done in {time.time()-t0:.1f}s", flush=True)
+        except Exception:
+            failed += 1
+            print(f"# {name} FAILED:\n{traceback.format_exc()}", file=sys.stderr, flush=True)
     if args.trace_out and runner.tracer.spans:
         from repro.telemetry.export import flame_summary, save_trace
         save_trace(runner.tracer.export(), args.trace_out)

@@ -1,5 +1,6 @@
-"""Runner-level benchmarks: reuse vs the seed path, and serial-vs-sharded
-dispatch on a multi-arch sweep.
+"""Runner-level benchmarks: reuse vs the seed path, serve throughput,
+profiling and metrics overhead, kernel autotuning, and admission policies.
+Every cell runs in this process.
 
 Part 1 — reuse (PR 1): a repeated-arch sweep in the shape regression CI
 produces every night — all three tasks of one arch, then the train cell
@@ -8,64 +9,31 @@ rebuilt the model and re-jitted for every measurement; the unified runner
 shares one arch build across tasks and replays cached executables on
 re-measures.
 
-Part 2 — sharded dispatch (``run_matrix(..., jobs=N)``): a multi-arch
-sweep measured three ways, all with ``runs``/warmup/compile-warmup held
-identical —
+Part 2 — serve throughput: one small continuous-batching cell per sweep
+arch (``task="serve"``, bursty trace) run through ``run_matrix``,
+reporting tok/s per cell and the sweep wall.
 
-    serial        in-process ``run_matrix`` (no fault containment: one
-                  segfaulting cell kills the sweep);
-    isolated      ``isolate=True`` — one fresh subprocess per cell, the
-                  pre-sharding way to make crashy cells recoverable; pays
-                  interpreter startup + arch rebuild for EVERY cell;
-    sharded       ``jobs=N`` persistent workers — same per-cell fault
-                  containment as ``isolated``, but each worker amortises
-                  its startup and keeps arch-build/executable caches hot
-                  across its shard.
-
-The headline ``shard_speedup`` is isolated/sharded — the two dispatch
-modes with equal crash-containment guarantees.  ``serial/sharded`` is also
-reported; how far it can exceed 1.0 is bounded by the host's real parallel
-capacity, so we probe that too (``parallel_capacity``: aggregate
-throughput of N busy processes vs 1 — ~1.1 on a hyperthread pair, ~N on N
-real cores) and report it alongside.
-
-Part 3 — serve throughput: one small continuous-batching cell per sweep
-arch (``task="serve"``, bursty trace) dispatched through the same sharded
-pool, reporting tok/s per cell and the sweep wall next to the
-serial/isolated/sharded walls.
-
-Part 4 — profiling overhead: the same cell measured unprofiled then
+Part 3 — profiling overhead: the same cell measured unprofiled then
 profiled (``profile=True``) on a warm executable cache; the reported
 ratio of median step times is the profiler's measurement tax (the
 acceptance bound is <10% — the phase split is two extra perf_counter
 reads per step, and the attribution compile happens outside the timed
 loop), so overhead regressions show up in the perf trajectory.
 
-Part 5 — scheduling strategies on a skew-weighted matrix: four
-equal-*guessed*-weight build-key groups where an injected slowdown makes
-the first-ranked group far heavier than the task-weight table believes.
-Static LPT (placed up front by the wrong guess) stacks a second group
-behind the slow one; dynamic stealing lets the free worker drain the
-tail; cluster ``local:2`` runs the same dynamic schedule over the socket
-transport (its delta over stealing is coordinator + worker-startup
-overhead).  The ``steal_win`` row is static/stealing wall — > 1.0
-whenever guessed and actual cost diverge, which is the load-balance case
-the deque exists for.
-
-Part 6 — kernel autotuning (``repro.tuning``): flash-attention + rglru
-(+ ssd when not ``--fast``) launch-parameter sweeps dispatched as
-``task="kernel"`` cells through the same sharded pool, winners recorded
-in the tuning DB (``results/tuning_db.json``), and the tuned-vs-default
-median ratio reported per kernel.  The ratio is >= 1.0 by construction —
-the ops default is always a swept candidate and the winner is the argmin
-(ties to the default), so the DB never serves a config slower than the
+Part 4 — kernel autotuning (``repro.tuning``): flash-attention + rglru
+(+ ssd when not ``--fast``) launch-parameter sweeps run as
+``task="kernel"`` cells, winners recorded in the tuning DB
+(``results/tuning_db.json``), and the tuned-vs-default median ratio
+reported per kernel.  The ratio is >= 1.0 by construction — the ops
+default is always a swept candidate and the winner is the argmin (ties
+to the default), so the DB never serves a config slower than the
 default it replaces.  The detector bridge is then demonstrated end to
 end: the three sweep archs are profiled, ``low_util`` is forced to fire
 (``util_rel=1.0`` flags every below-median cell — deterministic with 3+
 distinct cells), and the resulting findings enqueue tuning jobs into
 ``results/tuning_queue.json``.
 
-Part 7 — admission policies: the same queue-forming trace (bursty
+Part 5 — admission policies: the same queue-forming trace (bursty
 bimodal arrivals, offered load compressed so admission waves actually
 form) replayed under ``admission="batched"`` (one jitted prefill per
 wave, bucketed padded shapes) and ``admission="single"`` (the
@@ -73,35 +41,24 @@ one-prefill-per-request baseline).  Reported: the TTFT p99 ratio, the
 jitted prefill-call counts, and the token-digest equality gate — batched
 admission must be a pure scheduling change, byte-identical tokens.
 
-Part 8 — cluster capacity pipelining: the same single-build-key matrix
-dispatched to ONE cluster worker at ``--capacity 1`` (strict
-request/response round trips) and ``--capacity 2`` (the coordinator
-keeps two cells of the group in flight, so protocol latency + result
-marshalling overlap the worker's compute).  The capacity-2 run is span-
-traced end to end and the stitched Chrome trace is persisted as the
-*explanatory artifact* (``results/capacity_trace.json``): the wall-clock
-ratio says whether pipelining pays, the trace shows exactly where —
-dispatch spans overlapping on the coordinator lane vs back-to-back.
-
-Part 9 — metrics-registry overhead: the same warm cell measured with
+Part 6 — metrics-registry overhead: the same warm cell measured with
 the fleet metrics registry (``repro.fleet.metrics``) enabled — the
 default; every execution feeds it — and disabled.  The registry's
 ``record_result`` is a handful of dict increments under one lock,
 entirely outside the timed measurement loop, so both the median-step
 ratio and the end-to-end ``run()`` wall ratio must be ~1.0x — the
 "near-zero cost when unexported" acceptance bound, kept in the perf
-trajectory like the profiler tax of part 4.
+trajectory like the profiler tax of part 3.
 
 Numbers land in ``results/runner_bench.json``."""
 from __future__ import annotations
 
 import gc
 import json
-import multiprocessing
 import time
 
 from benchmarks.common import emit, results_path
-from repro.core.harness import RegressionHook, measure
+from repro.core.harness import measure
 from repro.core.suite import get_benchmark
 from repro.runner import BenchmarkRunner, Scenario, ScenarioMatrix
 from repro.profiler import Thresholds, detect
@@ -112,7 +69,6 @@ ARCH = "gemma-2b"
 BATCH, SEQ = 2, 32
 
 SWEEP_ARCHS = ["gemma-2b", "mamba2-2.7b", "recurrentgemma-9b", "mixtral-8x7b"]
-JOBS = 2
 
 
 def _workload(fast: bool):
@@ -142,43 +98,6 @@ def runner_path(scenarios, runs: int) -> tuple:
     return time.perf_counter() - t0, runner.stats
 
 
-# ---- part 2: dispatch-mode comparison -------------------------------------
-
-def _burn(out, seconds: float, barrier=None) -> None:
-    if barrier is not None:    # children sync up so their windows overlap
-        barrier.wait()
-    count, t0 = 0, time.perf_counter()
-    while time.perf_counter() - t0 < seconds:
-        count += 1
-    out.value = count
-
-
-def parallel_capacity(n: int = JOBS, seconds: float = 1.5) -> float:
-    """Aggregate busy-loop throughput of ``n`` processes vs 1 — the host's
-    real parallel headroom (hyperthreads and cgroup quotas both cap it).
-    Spawned, not forked (this process has a live multithreaded JAX), and
-    barrier-gated so the children's burn windows truly overlap despite
-    uneven interpreter start-up."""
-    ctx = multiprocessing.get_context("spawn")
-    single = ctx.Value("d")
-    _burn(single, seconds)
-    barrier = ctx.Barrier(n)
-    vals = [ctx.Value("d") for _ in range(n)]
-    procs = [ctx.Process(target=_burn, args=(v, seconds, barrier))
-             for v in vals]
-    for p in procs:
-        p.start()
-    for p in procs:
-        p.join()
-    return sum(v.value for v in vals) / max(single.value, 1.0)
-
-
-def _sweep_matrix(fast: bool) -> ScenarioMatrix:
-    archs = SWEEP_ARCHS[:2] if fast else SWEEP_ARCHS
-    return ScenarioMatrix(archs=archs, tasks=("train", "infer_decode"),
-                          batches=(BATCH,), seqs=(SEQ,))
-
-
 def _serve_matrix(fast: bool) -> ScenarioMatrix:
     """A small serving cell per sweep arch: the serve-throughput row."""
     archs = SWEEP_ARCHS[:1] if fast else SWEEP_ARCHS[:2]
@@ -189,11 +108,10 @@ def _serve_matrix(fast: bool) -> ScenarioMatrix:
 
 def scenario_matrices(fast: bool = False):
     """The matrices this benchmark executes (``benchmarks.run --list`` hook)."""
-    return [_sweep_matrix(fast), _serve_matrix(fast), _skew_matrix(fast),
-            _tuning_matrix(fast), _capacity_matrix(fast)]
+    return [_serve_matrix(fast), _tuning_matrix(fast)]
 
 
-# ---- part 6: kernel autotuning --------------------------------------------
+# ---- part 4: kernel autotuning --------------------------------------------
 
 def _tuning_cases(fast: bool):
     """Small tuning cases sized like the probe cells above: one per Pallas
@@ -215,108 +133,6 @@ def _tuning_matrix(fast: bool) -> ScenarioMatrix:
                         max_candidates=_tuning_candidates(fast))
 
 
-# ---- part 8: cluster capacity pipelining ----------------------------------
-
-def _capacity_matrix(fast: bool) -> ScenarioMatrix:
-    """One build-key group of several cheap cells: a single worker owns
-    the whole group, so any wall-clock gap between capacity 1 and 2 is
-    pure dispatch pipelining (not scheduling or cache effects)."""
-    return ScenarioMatrix(archs=[ARCH], tasks=("train", "infer_decode"),
-                          batches=(1, 2), seqs=(8 if fast else 16,))
-
-
-def capacity_path(matrix: ScenarioMatrix, *, capacity: int,
-                  tracer=None) -> float:
-    """Wall time of the matrix through one ``local:1`` cluster worker
-    advertising ``capacity`` in-flight cells; optionally span-traced."""
-    from repro.runner.cluster.scheduler import ClusterScheduler
-    from repro.telemetry.spans import NULL_TRACER
-    tr = tracer or NULL_TRACER
-    sched = ClusterScheduler("local:1", runs=1, warmup=0, compile_warmup=0,
-                             measure_fence=False, capacity=capacity)
-    t0 = time.perf_counter()
-    try:
-        root = None
-        if tr.enabled:
-            tr.begin_trace()
-            root = tr.start("matrix", kind="matrix", cells=len(matrix),
-                            transport=f"cluster:local:1;capacity={capacity}")
-        results, _ = sched.run(matrix.expand(), hooks={}, tracer=tracer,
-                               trace_parent=root)
-        if root is not None:
-            tr.finish(root)
-    finally:
-        sched.close()
-    wall = time.perf_counter() - t0
-    bad = [rr for rr in results if rr.status != "ok"]
-    if bad:
-        raise RuntimeError(f"{bad[0].name}: {bad[0].error}")
-    return wall
-
-
-# ---- part 5: static LPT vs stealing vs cluster ----------------------------
-
-def _skew_matrix(fast: bool) -> ScenarioMatrix:
-    """Four build-key groups of ONE arch (dtypes x reduced-config modes,
-    one train cell each, roughly equal real cost) with EQUAL guessed
-    weights — only the hook below skews the actual cost, so the
-    static-vs-stealing gap isolates the scheduling decision.  (Mixing
-    archs here buries the effect: their real cost spread dwarfs the
-    injected skew.)"""
-    return ScenarioMatrix(archs=[ARCH], tasks=("train",),
-                          batches=(1,), seqs=(8 if fast else 16,),
-                          dtypes=("fp32", "bf16"),
-                          modes=("jit_donated", "jit_noremat"))
-
-
-def _skew_hooks(matrix: ScenarioMatrix, slow_s: float) -> dict:
-    """Slow the FIRST-ranked group's cell: on equal weights the ranking is
-    first-appearance order, so static LPT seeds it on shard 0 and then —
-    trusting the wrong guess — stacks the third group behind it, while a
-    stealing worker that drew a fast group drains the tail instead."""
-    first = matrix.expand()[0]
-    return {first.name: RegressionHook(slowdown_s=slow_s)}
-
-
-def sched_path(matrix: ScenarioMatrix, hooks: dict, *, jobs: int = 0,
-               steal: bool = True, cluster: str = "") -> float:
-    """Wall time of one dispatch strategy; runs=1/warmup=0/compile_warmup=0
-    so the injected slowdown fires exactly once per measured cell."""
-    runner = BenchmarkRunner(runs=1, warmup=0, compile_warmup=0, jobs=jobs,
-                             steal=steal, cluster=cluster,
-                             measure_fence=False)
-    t0 = time.perf_counter()
-    try:
-        results = runner.run_matrix(matrix, hooks=hooks)
-    finally:
-        runner.close()
-    wall = time.perf_counter() - t0
-    bad = [rr for rr in results if rr.status != "ok"]
-    if bad:
-        raise RuntimeError(f"{bad[0].name}: {bad[0].error}")
-    return wall
-
-
-def dispatch_path(matrix: ScenarioMatrix, runs: int, *, jobs: int = 0,
-                  isolate: bool = False) -> tuple:
-    # fence off: this measures dispatch throughput, not per-cell latency
-    runner = BenchmarkRunner(runs=runs, jobs=jobs, isolate=isolate,
-                             measure_fence=False)
-    t0 = time.perf_counter()
-    try:
-        results = runner.run_matrix(matrix)
-    finally:
-        runner.close()
-    wall = time.perf_counter() - t0
-    bad = [rr for rr in results if rr.status != "ok"]
-    if bad:
-        raise RuntimeError(f"{bad[0].name}: {bad[0].error}")
-    stats = runner.stats
-    del runner, results
-    gc.collect()     # drop cached builds/executables before the next mode
-    return wall, stats
-
-
 def main(fast: bool = False, runner=None) -> None:
     runs = 2 if fast else 3
     scenarios = _workload(fast)
@@ -328,31 +144,14 @@ def main(fast: bool = False, runner=None) -> None:
          f"model_builds={stats.model_builds};exec_cache_hits={stats.executable_cache_hits}")
     emit("runner_bench/reuse_speedup", 0.0, f"{speedup:.2f}x")
 
-    matrix = _sweep_matrix(fast)
-    serial_s, _ = dispatch_path(matrix, runs)
-    isolated_s, _ = dispatch_path(matrix, runs, isolate=True)
-    sharded_s, shard_stats = dispatch_path(matrix, runs, jobs=JOBS)
-    capacity = parallel_capacity(JOBS)
-    shard_speedup = isolated_s / sharded_s if sharded_s else 0.0
-    serial_ratio = serial_s / sharded_s if sharded_s else 0.0
-    emit("runner_bench/sweep_serial_s", serial_s * 1e6, f"{len(matrix)}_cells")
-    emit("runner_bench/sweep_isolated_s", isolated_s * 1e6, "subprocess_per_cell")
-    emit("runner_bench/sweep_sharded_s", sharded_s * 1e6,
-         f"jobs={JOBS};worker_model_builds={shard_stats.model_builds}")
-    emit("runner_bench/shard_speedup_vs_isolated", 0.0, f"{shard_speedup:.2f}x")
-    emit("runner_bench/shard_ratio_vs_serial", 0.0,
-         f"{serial_ratio:.2f}x;host_parallel_capacity={capacity:.2f}")
-
-    # serve-throughput row: continuous-batching cells dispatched through the
-    # same sharded pool as the step sweep above (fence off: throughput run)
+    # serve-throughput row: continuous-batching cells through run_matrix
     serve_matrix = _serve_matrix(fast)
-    serve_runner = BenchmarkRunner(jobs=JOBS, measure_fence=False)
+    serve_runner = BenchmarkRunner()
     t0 = time.perf_counter()
-    try:
-        serve_results = serve_runner.run_matrix(serve_matrix)
-    finally:
-        serve_runner.close()
+    serve_results = serve_runner.run_matrix(serve_matrix)
     serve_wall = time.perf_counter() - t0
+    del serve_runner
+    gc.collect()
     serve_rows = []
     for rr in serve_results:
         if rr.status != "ok":
@@ -360,13 +159,12 @@ def main(fast: bool = False, runner=None) -> None:
         serve_rows.append({"name": rr.name,
                            "tok_per_s": rr.extra["tok_per_s"],
                            "ttft_p50_us": rr.extra.get("ttft_p50"),
-                           "tok_lat_p99_us": rr.extra.get("tok_lat_p99"),
-                           "shard": rr.extra.get("shard")})
+                           "tok_lat_p99_us": rr.extra.get("tok_lat_p99")})
         emit(f"runner_bench/serve_tok_per_s/{rr.arch}", 0.0,
              f"{rr.extra['tok_per_s']:.1f}tok_s;trace={rr.extra['trace']};"
              f"slots={rr.extra['slots']}")
-    emit("runner_bench/serve_sharded_s", serve_wall * 1e6,
-         f"jobs={JOBS};{len(serve_rows)}_serve_cells")
+    emit("runner_bench/serve_sweep_s", serve_wall * 1e6,
+         f"{len(serve_rows)}_serve_cells")
 
     # profiling overhead: unprofiled vs profiled median step time on a
     # warm executable (fresh compile settled by the first run)
@@ -408,41 +206,17 @@ def main(fast: bool = False, runner=None) -> None:
     del met_runner
     gc.collect()
 
-    # scheduling strategies: static LPT vs dynamic stealing vs cluster
-    # local:2 on the skew-weighted matrix (see module docstring, part 5)
-    # the slowdown must make the hooked group cost ~2x a normal group
-    # (build + jit ~8-10s here): that is the regime where static LPT
-    # stacks a second group behind the slow one and stealing does not
-    skew_matrix = _skew_matrix(fast)
-    slow_s = 18.0 if fast else 22.0
-    hooks = _skew_hooks(skew_matrix, slow_s)
-    static_s = sched_path(skew_matrix, hooks, jobs=JOBS, steal=False)
-    steal_s = sched_path(skew_matrix, hooks, jobs=JOBS, steal=True)
-    cluster_s = sched_path(skew_matrix, hooks, cluster=f"local:{JOBS}")
-    steal_win = static_s / steal_s if steal_s else 0.0
-    cluster_ratio = cluster_s / steal_s if steal_s else 0.0
-    emit("runner_bench/sched_static_lpt_s", static_s * 1e6,
-         f"jobs={JOBS};{len(skew_matrix)}_cells;slow_cell={slow_s:.0f}s")
-    emit("runner_bench/sched_stealing_s", steal_s * 1e6, f"jobs={JOBS}")
-    emit("runner_bench/sched_cluster_s", cluster_s * 1e6,
-         f"local:{JOBS};socket_transport")
-    emit("runner_bench/steal_win_vs_static", 0.0,
-         f"{steal_win:.2f}x;cluster_vs_steal={cluster_ratio:.2f}x")
-
-    # kernel autotuning: per-kernel candidate sweeps through the sharded
-    # pool, winners recorded in the tuning DB, tuned-vs-default ratio per
-    # kernel (fence ON here — candidate medians must be comparable, so the
-    # timed loops serialize while builds/compiles still overlap)
+    # kernel autotuning: per-kernel candidate sweeps, winners recorded in
+    # the tuning DB, tuned-vs-default ratio per kernel
     cases = _tuning_cases(fast)
     tuning_db = TuningDB.load(results_path("tuning_db.json"))
-    tune_runner = BenchmarkRunner(runs=max(3, runs), jobs=JOBS)
+    tune_runner = BenchmarkRunner(runs=max(3, runs))
     t0 = time.perf_counter()
-    try:
-        tuning = run_sweep(cases, tune_runner, db=tuning_db,
-                           max_candidates=_tuning_candidates(fast))
-    finally:
-        tune_runner.close()
+    tuning = run_sweep(cases, tune_runner, db=tuning_db,
+                       max_candidates=_tuning_candidates(fast))
     tuning_wall = time.perf_counter() - t0
+    del tune_runner
+    gc.collect()
     for row in tuning["cases"]:
         if row["status"] != "ok":
             raise RuntimeError(f"tuning sweep failed for {row['case']}")
@@ -451,7 +225,7 @@ def main(fast: bool = False, runner=None) -> None:
              f"{row['ratio']:.2f}x;winner={winner};"
              f"default_us={row['default_us']:.0f}")
     emit("runner_bench/tuning_sweep_s", tuning_wall * 1e6,
-         f"jobs={JOBS};{sum(r['candidates'] for r in tuning['cases'])}"
+         f"{sum(r['candidates'] for r in tuning['cases'])}"
          f"_candidates;db={tuning['db_path']}")
 
     # detector bridge: profile the three kernel-bearing archs, force
@@ -477,7 +251,7 @@ def main(fast: bool = False, runner=None) -> None:
     # admission policies: batched wave prefill vs per-request baseline on
     # the same queue-forming trace (loadgen at a compressed offered load —
     # native bursty arrivals rarely queue >1 request against free slots)
-    adm_runner = BenchmarkRunner(measure_fence=False)
+    adm_runner = BenchmarkRunner()
     adm_cells = {}
     try:
         for adm in ("batched", "single"):
@@ -510,37 +284,11 @@ def main(fast: bool = False, runner=None) -> None:
          f"vs{adm_cells['single']['prefill_calls']};"
          f"batch_max={adm_cells['batched']['admit_batch_max']}")
 
-    # cluster capacity pipelining: one worker, strict round trips vs two
-    # cells in flight; the traced capacity-2 run is the explanatory
-    # artifact (see module docstring, part 8)
-    from repro.telemetry.export import save_trace
-    from repro.telemetry.spans import Tracer
-    cap_matrix = _capacity_matrix(fast)
-    cap1_s = capacity_path(cap_matrix, capacity=1)
-    cap_tracer = Tracer()
-    cap2_s = capacity_path(cap_matrix, capacity=2, tracer=cap_tracer)
-    cap_trace_path = results_path("capacity_trace.json")
-    save_trace(cap_tracer.export(), cap_trace_path)
-    cap_ratio = cap1_s / cap2_s if cap2_s else 0.0
-    emit("runner_bench/capacity1_s", cap1_s * 1e6,
-         f"local:1;{len(cap_matrix)}_cells")
-    emit("runner_bench/capacity2_s", cap2_s * 1e6,
-         f"local:1;pipelined;trace={cap_trace_path}")
-    emit("runner_bench/capacity_pipelining_win", 0.0, f"{cap_ratio:.2f}x")
-
     with open(results_path("runner_bench.json"), "w") as f:
         json.dump({"scenarios": [s.name for s in scenarios], "runs": runs,
                    "seed_path_s": seed_s, "runner_path_s": runner_s,
                    "speedup": speedup, "runner_stats": stats.to_dict(),
-                   "sweep": {"cells": [s.name for s in matrix],
-                             "jobs": JOBS, "serial_s": serial_s,
-                             "isolated_s": isolated_s, "sharded_s": sharded_s,
-                             "shard_speedup_vs_isolated": shard_speedup,
-                             "shard_ratio_vs_serial": serial_ratio,
-                             "host_parallel_capacity": capacity,
-                             "sharded_stats": shard_stats.to_dict()},
-                   "serve": {"jobs": JOBS, "wall_s": serve_wall,
-                             "cells": serve_rows},
+                   "serve": {"wall_s": serve_wall, "cells": serve_rows},
                    "profile": {"cell": sc.name,
                                "base_median_us": base_rr.median_us,
                                "profiled_median_us": prof_rr.median_us,
@@ -550,23 +298,10 @@ def main(fast: bool = False, runner=None) -> None:
                                "disabled_median_us": off_rr.median_us,
                                "overhead_ratio": metrics_ratio,
                                "wall_ratio": metrics_wall_ratio},
-                   "scheduling": {"cells": [s.name for s in skew_matrix],
-                                  "jobs": JOBS, "slow_cell_s": slow_s,
-                                  "static_lpt_s": static_s,
-                                  "stealing_s": steal_s,
-                                  "cluster_local_s": cluster_s,
-                                  "steal_win_vs_static": steal_win,
-                                  "cluster_ratio_vs_steal": cluster_ratio},
                    "admission": {"cells": adm_cells,
                                  "digests_match": adm_digest_ok,
                                  "ttft_p99_ratio": adm_ttft_ratio},
-                   "capacity": {"cells": [s.name for s in cap_matrix],
-                                "capacity1_s": cap1_s,
-                                "capacity2_s": cap2_s,
-                                "pipelining_win": cap_ratio,
-                                "trace_path": str(cap_trace_path),
-                                "trace_spans": len(cap_tracer.spans)},
-                   "tuning": {"jobs": JOBS, "wall_s": tuning_wall,
+                   "tuning": {"wall_s": tuning_wall,
                               "db_path": tuning["db_path"],
                               "cases": tuning["cases"],
                               "recorded": tuning["recorded"],

@@ -5,8 +5,7 @@ Per (arch x slots x trace) cell we report the latency distribution a
 production user compares — TTFT and per-token p50/p95/p99 plus tok/s —
 computed by the serve engine (``repro.launch.serve``) over deterministic
 load profiles (``repro.runner.traces``).  The sweep is one ``run_matrix``
-call: it shards across ``--jobs N`` workers like every other table, and
-every cell lands in the shared ResultStore under the well-known serve
+call, and every cell lands in the shared ResultStore under the well-known serve
 extra keys (``repro/runner/results.py``).
 
 Rows land in ``results/serve_latency.json``.

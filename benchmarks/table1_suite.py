@@ -8,7 +8,7 @@ MLPerf" claim, reproduced quantitatively).
 Measurement goes through the shared ``BenchmarkRunner``: the coverage
 tracer and the timing pass reuse one arch build each, every row lands in
 the persistent ResultStore, and the timing sweep is one ``run_matrix``
-call — shardable across worker subprocesses with ``--jobs N``."""
+call."""
 from __future__ import annotations
 
 import json

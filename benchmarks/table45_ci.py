@@ -82,14 +82,5 @@ if __name__ == "__main__":
     import argparse
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
-    ap.add_argument("--jobs", type=int, default=0,
-                    help="runner shard setting (CLI parity with benchmarks.run);"
-                         " this table's single-cell baseline/injection/bisect"
-                         " re-measures are inherently serial and always run"
-                         " in-process — sharding applies to matrix sweeps")
     args = ap.parse_args()
-    _runner = make_runner(jobs=args.jobs)
-    try:
-        main(fast=args.fast, runner=_runner)
-    finally:
-        _runner.close()
+    main(fast=args.fast, runner=make_runner())

@@ -3,7 +3,7 @@ nightly workflow: nightly suite run, baseline store, an injected "bad
 commit", detection at the 7% threshold, and binary-search bisection to
 the culprit.
 
-    PYTHONPATH=src python examples/regression_ci.py [--jobs N]
+    PYTHONPATH=src python examples/regression_ci.py
 
 Each ``run_nightly`` call does two things with the ``MetricStore``:
 
@@ -22,13 +22,7 @@ host's rolling baseline.  After the two nights below, the trajectory
 report (rendered at the end, same machinery as
 ``benchmarks/history_report.py``) shows a >=2-point series per probe
 cell with the injected regression visible as its drift finding.
-
-``--jobs N`` shards each night's matrix across N persistent worker
-subprocesses (the injected hooks cross the process boundary as plain
-slowdown/leak parameters); the pool keeps worker caches warm across
-nights.
 """
-import argparse
 import os
 import sys
 import tempfile
@@ -41,27 +35,15 @@ from repro.core.regression import Commit, MetricStore, bisect_commits  # noqa: E
 from repro.runner import BenchmarkRunner, Scenario  # noqa: E402
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--jobs", type=int, default=0,
-                    help="shard nightly matrix runs across N worker subprocesses")
-    args = ap.parse_args(argv)
+def main() -> int:
     # a private mkdtemp dir, not the race-prone mktemp: the directory is
     # ours atomically, so the store path inside it can't be hijacked
     store = MetricStore(os.path.join(
         tempfile.mkdtemp(prefix="regression_ci_"), "store.json"))
     archs = ["gemma-2b", "mamba2-2.7b"]
     # one runner for the whole CI day: nights and bisection probes share
-    # cached arch builds and compiled executables (and, with --jobs, the
-    # persistent shard workers' caches)
-    runner = BenchmarkRunner(runs=3, jobs=args.jobs)
-    try:
-        return _ci_day(store, archs, runner)
-    finally:
-        runner.close()       # shard workers must die even on a failed assert
-
-
-def _ci_day(store, archs, runner) -> int:
+    # cached arch builds and compiled executables
+    runner = BenchmarkRunner(runs=3)
     # small probe cells: a ~10ms step means the injected 50ms/step
     # regression is a 4-5x blowup that shared-host timing jitter (easily
     # +-50% on busy boxes) can never mask at the 7% threshold

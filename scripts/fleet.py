@@ -2,8 +2,8 @@
 """The fleet perf-CI service CLI: supervised scheduled sweeps with live
 metrics, drift triage, and automatic re-measure + bisect.
 
-    PYTHONPATH=src python scripts/fleet.py --ticks N [--fast] [--jobs N]
-        [--cluster SPEC] [--results-dir DIR] [--interval-s S]
+    PYTHONPATH=src python scripts/fleet.py --ticks N [--fast]
+        [--results-dir DIR] [--interval-s S]
 
 ``--fast`` is the bounded demo/CI mode on a virtual clock: a 2-cell
 matrix (gemma-2b train, fp32 + bf16), an injected ``RegressionHook``
@@ -85,10 +85,6 @@ def main(argv=None) -> int:
     ap.add_argument("--fast", action="store_true",
                     help="bounded demo: virtual clock, 2-cell matrix, "
                          "injected regression + synthetic commit day")
-    ap.add_argument("--jobs", type=int, default=0,
-                    help="shard each tick's matrix across N workers")
-    ap.add_argument("--cluster", default="",
-                    help="cluster spec for tick dispatch (e.g. local:2)")
     ap.add_argument("--runs", type=int, default=3)
     ap.add_argument("--interval-s", type=float, default=0.0,
                     help="clock sleep between ticks (virtual under --fast)")
@@ -120,18 +116,14 @@ def main(argv=None) -> int:
         clock = None
 
     store = MetricStore(store_path)
-    runner = BenchmarkRunner(runs=args.runs, jobs=args.jobs,
-                             cluster=args.cluster, coverage=True)
+    runner = BenchmarkRunner(runs=args.runs, coverage=True)
     service = FleetService(
         cfg, store=store, runner=runner, results_dir=args.results_dir,
         clock=clock,
         hooks_for_tick=_fast_hooks if args.fast else None,
         commits_for=_fast_commits_for(runner) if args.fast else None,
         backoff_s=0.5)
-    try:
-        summary = service.run(args.ticks)
-    finally:
-        runner.close()
+    summary = service.run(args.ticks)
 
     print(f"fleet: {summary['ticks']} ticks, {summary['restarts']} restarts, "
           f"{summary['open_findings']} open findings")

@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
 # PR smoke gate: tier-1 tests + the runner-driven table1 path end-to-end
-# + a sharded (--jobs 2) run_matrix smoke.
+# + serve, profile, tuning, trace, history and fleet gates.
 #
 #     bash scripts/smoke.sh [--fast-only]
 #
@@ -117,60 +117,7 @@ print("  " + rr.name + ": " +
 print("profiled smoke OK")
 EOF
 
-echo "== sharded dispatch: 2-cell matrix across --jobs 2 workers =="
-python - <<'EOF'
-from repro.runner import BenchmarkRunner, ScenarioMatrix
-
-matrix = ScenarioMatrix(archs=["gemma-2b"], tasks=("train",),
-                        batches=(1,), seqs=(8,), dtypes=("fp32", "bf16"))
-runner = BenchmarkRunner(runs=1, warmup=0, jobs=2)
-try:
-    results = runner.run_matrix(matrix)
-finally:
-    runner.close()
-for rr in results:
-    print(f"  {rr.name}: {rr.status} (shard {rr.extra.get('shard')})")
-    assert rr.status == "ok", rr.error
-assert {rr.extra.get("shard") for rr in results} == {0, 1}
-assert runner.stats.model_builds == 2, runner.stats.to_dict()
-print("sharded smoke OK")
-EOF
-
-echo "== cluster dispatch: local:2 socket workers match serial, no orphans =="
-python - <<'EOF'
-import os
-
-from repro.runner import BenchmarkRunner, ScenarioMatrix
-
-matrix = ScenarioMatrix(archs=["gemma-2b"], tasks=("train",),
-                        batches=(1,), seqs=(8,), dtypes=("fp32", "bf16"))
-serial = BenchmarkRunner(runs=1, warmup=0)
-serial_names = [rr.name for rr in serial.run_matrix(matrix)]
-
-runner = BenchmarkRunner(runs=1, warmup=0)
-try:
-    results = runner.run_matrix(matrix, cluster="local:2")
-    pids = runner.cluster_worker_pids()
-finally:
-    runner.close()
-for rr in results:
-    print(f"  {rr.name}: {rr.status} (host {rr.extra.get('host')})")
-    assert rr.status == "ok", rr.error
-    assert rr.extra.get("host", "").startswith("local"), rr.extra
-assert [rr.name for rr in results] == serial_names
-assert runner.stats.model_builds >= 1, runner.stats.to_dict()
-# coordinator shutdown must leave no orphan worker processes
-assert len(pids) == 2
-for pid in pids:
-    try:
-        os.kill(pid, 0)
-    except ProcessLookupError:
-        continue
-    raise AssertionError(f"orphan cluster worker pid {pid}")
-print("cluster smoke OK")
-EOF
-
-echo "== tuning gate: 2-candidate sweep under --jobs 2, DB hit on next trace =="
+echo "== tuning gate: 2-candidate sweep, DB hit on next trace =="
 python - <<'EOF'
 import os
 import tempfile
@@ -185,12 +132,8 @@ import jax
 import jax.numpy as jnp
 
 case = make_case("flash_attention", B=1, S=64, H=2, K=2, D=32)
-runner = BenchmarkRunner(runs=1, warmup=0, compile_warmup=0, jobs=2,
-                         measure_fence=False)
-try:
-    summary = run_sweep([case], runner, max_candidates=2)
-finally:
-    runner.close()
+runner = BenchmarkRunner(runs=1, warmup=0, compile_warmup=0)
+summary = run_sweep([case], runner, max_candidates=2)
 row = summary["cases"][0]
 assert row["status"] == "ok", row
 assert os.path.exists(summary["db_path"]), summary["db_path"]
@@ -257,11 +200,8 @@ store = MetricStore(os.path.join(tempfile.mkdtemp(prefix="smoke_hist_"),
 probe = dict(archs=["gemma-2b"], tasks=("train",), batches=(1,), seqs=(8,),
              runs=2)
 runner = BenchmarkRunner(runs=2)
-try:
-    run_nightly(store, update_baseline=True, runner=runner, **probe)
-    run_nightly(store, runner=runner, **probe)
-finally:
-    runner.close()
+run_nightly(store, update_baseline=True, runner=runner, **probe)
+run_nightly(store, runner=runner, **probe)
 two_point = {k: pts for k, pts in series(store).items() if len(pts) >= 2}
 assert two_point, "no 2-point provenance series after two nights"
 for (name, prov), pts in sorted(two_point.items()):

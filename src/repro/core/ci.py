@@ -35,13 +35,11 @@ def run_nightly(store: MetricStore, *, archs: Optional[List[str]] = None,
                 batches=(2,), seqs=(64,),
                 update_baseline: bool = False,
                 hooks: Optional[Dict[str, RegressionHook]] = None,
-                runner: Optional[BenchmarkRunner] = None,
-                jobs: Optional[int] = None) -> NightlyReport:
-    """``jobs=N`` shards the night's matrix across N worker subprocesses
-    (defaults to the runner's own ``jobs`` setting); the persistent pool
-    keeps worker caches warm across repeated nights.  ``batches``/``seqs``
-    pick the probe cells — noisy shared hosts want small ones, so an
-    injected regression dwarfs host jitter.
+                runner: Optional[BenchmarkRunner] = None) -> NightlyReport:
+    """Measure the night's matrix on ``runner`` (a fresh one by default;
+    pass one in to keep its caches warm across repeated nights).
+    ``batches``/``seqs`` pick the probe cells — noisy shared hosts want
+    small ones, so an injected regression dwarfs host jitter.
 
     Every measured result (ok or error, baseline night or not) is also
     appended to the store's history log as a provenance-stamped
@@ -50,27 +48,22 @@ def run_nightly(store: MetricStore, *, archs: Optional[List[str]] = None,
     trajectories — without touching the baseline pointer."""
     t0 = time.perf_counter()
     issues: List[Issue] = []
-    owned = runner is None      # close what we create (shard workers!)
     runner = runner or BenchmarkRunner(runs=runs)
     matrix = ScenarioMatrix(archs=sorted(archs or ARCHS), tasks=tasks,
                             batches=batches, seqs=seqs)
     ran = 0
-    try:
-        for rr in runner.run_matrix(matrix, hooks=hooks, runs=runs, jobs=jobs):
-            ran += 1
-            store.log_result(rr)
-            if rr.status != "ok":
-                issues.append(Issue(benchmark=rr.bench, metric="status",
-                                    baseline=0.0, observed=0.0, increase=0.0,
-                                    culprit=rr.error))
-                continue
-            obs = rr.metrics()
-            if update_baseline:
-                store.update(rr.bench, obs)
-            else:
-                issues.extend(detect(store, rr.bench, obs))
-    finally:
-        if owned:
-            runner.close()
+    for rr in runner.run_matrix(matrix, hooks=hooks, runs=runs):
+        ran += 1
+        store.log_result(rr)
+        if rr.status != "ok":
+            issues.append(Issue(benchmark=rr.bench, metric="status",
+                                baseline=0.0, observed=0.0, increase=0.0,
+                                culprit=rr.error))
+            continue
+        obs = rr.metrics()
+        if update_baseline:
+            store.update(rr.bench, obs)
+        else:
+            issues.extend(detect(store, rr.bench, obs))
     return NightlyReport(ran=ran, issues=issues,
                          wall_s=time.perf_counter() - t0)

@@ -5,8 +5,8 @@
 a long-running, supervised service:
 
 * :mod:`repro.fleet.metrics` — the process-wide metrics registry
-  (counters / gauges / histograms) instrumenting runner, pool, cluster
-  coordinator, and serve engine; JSON + Prometheus export;
+  (counters / gauges / histograms) instrumenting the runner and the
+  serve engine; JSON + Prometheus export;
 * :mod:`repro.fleet.scheduler` — the tick-driven sweep loop (virtual
   clock injectable) appending provenance-stamped history points and
   running the drift pass + tuning-queue drain on a stride;
@@ -16,9 +16,9 @@ a long-running, supervised service:
   behind ``scripts/fleet.py``, with the heartbeat status file.
 
 Only the metrics module is imported eagerly — it is stdlib-only, so the
-runner / pool / coordinator / serve layers can ``import repro.fleet
-.metrics`` without dragging the scheduler's runner dependency into a
-cycle; everything else resolves lazily through ``__getattr__``.
+runner and serve layers can ``import repro.fleet.metrics`` without
+dragging the scheduler's runner dependency into a cycle; everything else
+resolves lazily through ``__getattr__``.
 """
 from repro.fleet.metrics import (METRICS_SCHEMA_KEY, METRICS_SCHEMA_VERSION,
                                  MetricsRegistry, registry, set_enabled)

@@ -2,16 +2,14 @@
 
 Counters, gauges, and bounded-reservoir histograms instrumenting the
 hot *control* paths — ``runner/runner.py`` (cells run/errored, cache
-hits/misses, compile vs measure seconds), ``runner/pool.py`` and
-``runner/cluster/coordinator.py`` (steals, respawns, worker deaths,
-heartbeat gaps, queue depth, per-worker in-flight), and
-``launch/serve.py`` (admission waves, bucket compiles, KV occupancy).
-Every mutation is a dict update under one lock and happens per cell /
-per job / per admission wave — never per decode step or per measured
-iteration — so the registry costs nothing measurable when nobody
-exports it (``benchmarks/runner_bench.py`` measures the enabled-vs-
-disabled ratio ~= 1.0x on a warm cell); ``enabled = False`` turns every
-mutation into an early return for belt-and-braces benchmarking.
+hits/misses, compile vs measure seconds) and ``launch/serve.py``
+(admission waves, bucket compiles, KV occupancy).  Every mutation is a
+dict update under one lock and happens per cell / per admission wave —
+never per decode step or per measured iteration — so the registry costs
+nothing measurable when nobody exports it
+(``benchmarks/runner_bench.py`` measures the enabled-vs-disabled ratio
+~= 1.0x on a warm cell); ``enabled = False`` turns every mutation into
+an early return for belt-and-braces benchmarking.
 
 Export surfaces:
 
@@ -23,19 +21,8 @@ Export surfaces:
   format (counters, gauges, and histograms as summaries with quantile
   labels).
 
-Cross-process merging: worker subprocesses carry their own registry;
-the flat cumulative-counter snapshot (:meth:`counters_cumulative`)
-rides the JSONL result channel next to ``RunnerStats`` (the
-``"metrics"`` field of a ``result`` message, see
-``runner/protocol.py``) and the dispatcher delta-merges it with the
-same ``protocol.stats_delta`` arithmetic — per-worker-process ``seen``
-snapshots, reset on respawn — so parent-side counters stay
-monotonically non-decreasing across worker respawns.  Histograms ship
-only their count/sum on the wire (percentile reservoirs don't merge);
-gauges are process-local and never cross.
-
-This module depends only on the stdlib, so any layer (runner, pool,
-coordinator, serve engine, worker) can import it without cycles.
+This module depends only on the stdlib, so any layer (runner, serve
+engine) can import it without cycles.
 """
 from __future__ import annotations
 
@@ -44,7 +31,7 @@ import re
 import threading
 import time
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional
+from typing import Any, Deque, Dict, List
 
 METRICS_SCHEMA_KEY = "fleet_metrics"
 METRICS_SCHEMA_VERSION = 1
@@ -53,12 +40,6 @@ METRICS_SCHEMA_VERSION = 1
 #: recent RESERVOIR observations; count/sum stay exact forever
 RESERVOIR = 256
 
-#: separator for flat histogram encoding on the wire ("|" never appears
-#: in metric names, see _NAME_OK)
-_HIST_COUNT = "|hcount"
-_HIST_SUM = "|hsum"
-
-_NAME_OK = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
 _PROM_BAD = re.compile(r"[^a-zA-Z0-9_:]")
 
 
@@ -109,8 +90,7 @@ class MetricsRegistry:
     # ---- mutation --------------------------------------------------------
 
     def inc(self, name: str, value: float = 1.0) -> None:
-        """Add to a monotonic counter (negative deltas are ignored —
-        counters must survive ``stats_delta`` merging)."""
+        """Add to a monotonic counter (negative deltas are ignored)."""
         if not self.enabled or value <= 0:
             return
         with self._lock:
@@ -144,9 +124,7 @@ class MetricsRegistry:
         """Count one scenario *execution* (a ``RunResult``): cells run /
         errored, executable-cache hit vs miss, and the compile/measure
         second distributions.  Called from the runner's result epilogue
-        on every transport — note the measurement fence's unfenced warm
-        pass is an execution too, so fenced cells count twice (the
-        ledger-corrected ``RunnerStats`` stay the one-per-cell view)."""
+        for every cell, recorded or not."""
         if not self.enabled:
             return
         self.inc("fleet_cells_total")
@@ -203,43 +181,6 @@ class MetricsRegistry:
             lines.append(f"{n}_sum {_prom_num(h['sum'])}")
             lines.append(f"{n}_count {_prom_num(h['count'])}")
         return "\n".join(lines) + "\n"
-
-    # ---- the wire (worker -> dispatcher) ---------------------------------
-
-    def counters_cumulative(self) -> Dict[str, float]:
-        """Flat, monotonically non-decreasing snapshot for the JSONL
-        result channel: counters verbatim plus each histogram's exact
-        count/sum under ``<name>|hcount`` / ``<name>|hsum`` keys — the
-        shape ``protocol.stats_delta`` can diff.  Gauges stay local."""
-        with self._lock:
-            out: Dict[str, float] = dict(self._counters)
-            for name, h in self._hists.items():
-                out[name + _HIST_COUNT] = float(h.count)
-                out[name + _HIST_SUM] = h.total
-            return out
-
-    def merge_cumulative(self, delta: Optional[Dict[str, float]]) -> None:
-        """Fold a worker's ``stats_delta``-diffed snapshot into this
-        registry.  Histogram count/sum merge exactly; the percentile
-        reservoir only sees locally-observed samples (cross-process
-        percentiles don't compose), so merged histograms report exact
-        count/sum with parent-local quantiles."""
-        if not delta or not self.enabled:
-            return
-        with self._lock:
-            for k, v in delta.items():
-                if not isinstance(v, (int, float)) or v <= 0:
-                    continue
-                if k.endswith(_HIST_COUNT):
-                    hist = self._hists.setdefault(k[: -len(_HIST_COUNT)],
-                                                  _Hist())
-                    hist.count += int(v)
-                elif k.endswith(_HIST_SUM):
-                    hist = self._hists.setdefault(k[: -len(_HIST_SUM)],
-                                                  _Hist())
-                    hist.total += float(v)
-                else:
-                    self._counters[k] = self._counters.get(k, 0.0) + v
 
 
 def _prom_name(name: str) -> str:

@@ -1,8 +1,7 @@
 """Tick-driven sweep scheduler for the fleet service.
 
 One tick = one nightly-shaped pass over the configured matrix through
-the shared :class:`~repro.runner.BenchmarkRunner` (so serial,
-``jobs=N``, and ``cluster=`` dispatch all work unchanged), with every
+the shared :class:`~repro.runner.BenchmarkRunner`, with every
 measured ``RunResult`` appended to the :class:`~repro.core.regression
 .MetricStore` history log as a provenance-stamped time-series point
 (``extra["fleet_tick"]`` records which tick measured it), followed by

@@ -38,7 +38,7 @@ FLEET_STATUS_SCHEMA_VERSION = 1
 
 #: the counters the status file tracks per tick (the smoke gate's
 #: monotonicity probe); everything else is in the full snapshot
-STATUS_COUNTER_PREFIXES = ("fleet_", "pool_", "cluster_", "serve_")
+STATUS_COUNTER_PREFIXES = ("fleet_", "serve_")
 
 
 class _TickCheckpoint:

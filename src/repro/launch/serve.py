@@ -1,6 +1,5 @@
 """Continuous-batching inference serving engine (the ``task="serve"``
-workload — NOT ``repro.runner.worker --serve``, which is the benchmark
-pool's worker-protocol flag; see the disambiguation note below).
+workload).
 
 A minimal production-shaped server: a request queue with virtual-time
 arrivals, a batched prefill admission stage, and a batched decode loop
@@ -31,25 +30,16 @@ Layering (ISSUE 3):
   profiles (uniform / bursty / mixed arrivals, optionally crossed with a
   prompt-length profile as ``"bursty+bimodal"``) whose arrivals are
   expressed in decode-step *virtual time*, so generated tokens are a pure
-  function of (trace spec, params) — identical serially and under sharded
-  dispatch.  Per-slot position vectors in the KV cache let one decode
-  batch mix prompt lengths; ``capture()`` turns a served trace back into
-  a replayable spec.
+  function of (trace spec, params) — identical on every replay.
+  Per-slot position vectors in the KV cache let one decode batch mix
+  prompt lengths; ``capture()`` turns a served trace back into a
+  replayable spec.
 * Latency distributions (TTFT and per-token p50/p95/p99) are produced by
   ``summarize_metrics`` on the engine's raw per-request timestamps,
   using the shared ``repro.runner.latency`` percentile helper.
 * The CLI at the bottom is a thin shell: resolve config -> build ->
   generate trace -> run engine -> print the summary.  Benchmarked runs
   go through ``BenchmarkRunner`` (``Scenario(task="serve")``) instead.
-
-Naming note: "serve" appears twice in this codebase with unrelated
-meanings.  THIS module is the inference-serving *workload*.  The
-``--serve`` flag of ``repro.runner.worker`` puts a benchmark worker into
-its persistent JSONL pool protocol over stdin/stdout pipes, and the
-worker's ``--connect HOST:PORT`` flag speaks the same protocol over TCP
-to a cluster coordinator (``repro.runner.cluster``) — both are dispatch
-transports that can be handed scenarios of any task, including this
-one's ``task="serve"`` cells.  Grep accordingly.
 
     PYTHONPATH=src python -m repro.launch.serve --arch gemma-2b \
         --requests 16 --slots 4 --prompt-len 32 --trace bursty

@@ -15,7 +15,7 @@ Four layers, all flowing through the unified BenchmarkRunner
     detectors    rule-based inefficiency findings (the paper's
                  optimization-catalog spirit): data-movement-bound,
                  low relative utilization, compile outliers, serve queue
-                 saturation, shard imbalance, dispatch-bound
+                 saturation, dispatch-bound
     report       ranked findings with severity + evidence, JSON + table
 
 The profile lands under the well-known ``extra["prof_*"]`` keys

@@ -4,7 +4,7 @@ The paper's first use case is profiling the suite to *find* GPU
 performance inefficiencies and drive optimization patches; these rules
 are the measured-profile analogue of that optimization catalog.  Each
 rule inspects RunResult dicts (the ``extra["prof_*"]`` payload plus the
-serve / sharding extras) and emits ranked ``Finding``s:
+serve extras) and emits ranked ``Finding``s:
 
     data_movement_bound    the cell's measured memory fraction dominates —
                            the classic fusion / layout / dtype patch target
@@ -17,16 +17,13 @@ serve / sharding extras) and emits ranked ``Finding``s:
                            median — guard-heavy or recompiling cells
     queue_saturation       serve cells whose arrival load sustainedly
                            exceeds the decode slots (queue_depth extras)
-    shard_imbalance        sharded sweeps whose slowest shard dwarfs the
-                           fastest — the LPT balance lost to a bad weight
-                           guess or a straggler cell
     dispatch_bound         host dispatch overhead rivals device work —
                            batch-too-small / sync-heavy cells
 
-Rules that need sweep context (low_util, compile_outlier,
-shard_imbalance) compute it from the record batch they're given; single
-records never fire them.  Thresholds live in one ``Thresholds`` config so
-tests can pin them and future backends can recalibrate.
+Rules that need sweep context (low_util, compile_outlier) compute it
+from the record batch they're given; single records never fire them.
+Thresholds live in one ``Thresholds`` config so tests can pin them and
+future backends can recalibrate.
 """
 from __future__ import annotations
 
@@ -70,8 +67,6 @@ class Thresholds:
     queue_factor: float = 1.0
     #: escalate to crit above slots * this factor
     queue_factor_crit: float = 2.0
-    #: sharded sweeps: slowest/fastest shard wall ratio that fires
-    shard_ratio: float = 1.5
     #: host dispatch fraction that rivals device work
     dispatch_frac: float = 0.35
 
@@ -191,28 +186,6 @@ def _compile_outliers(records: List[dict], th: Thresholds) -> List[Finding]:
     return out
 
 
-def _shard_imbalance(records: List[dict], th: Thresholds) -> List[Finding]:
-    walls: Dict[int, float] = {}
-    for r in records:
-        shard = _extra(r).get("shard")
-        if shard is None or not _ok(r):
-            continue
-        walls[shard] = walls.get(shard, 0.0) + (r.get("wall_s") or 0.0)
-    if len(walls) < 2:
-        return []
-    slow, fast = max(walls.values()), min(walls.values())
-    if fast <= 0.0 or slow / fast <= th.shard_ratio:
-        return []
-    return [Finding(
-        rule="shard_imbalance", severity="info", cell="<sweep>",
-        summary=f"slowest shard ran {slow:.1f}s vs fastest {fast:.1f}s "
-                f"({slow / fast:.1f}x) over {len(walls)} shards — "
-                f"rebalance weights or steal work",
-        score=slow / fast,
-        evidence={"shard_wall_s": {str(k): round(v, 2)
-                                   for k, v in sorted(walls.items())}})]
-
-
 def detect(records: Iterable[dict],
            th: Optional[Thresholds] = None) -> List[Finding]:
     """Run every rule over a batch of RunResult dicts; returns findings
@@ -232,6 +205,5 @@ def detect(records: Iterable[dict],
             findings.append(f)
     findings += _low_util(recs, th)
     findings += _compile_outliers(recs, th)
-    findings += _shard_imbalance(recs, th)
     findings.sort(key=lambda f: (SEVERITIES.index(f.severity), -f.score))
     return findings

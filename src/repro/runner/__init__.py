@@ -3,18 +3,13 @@
 Public surface:
 
     Scenario, ScenarioMatrix     declarative execution matrix
-    BenchmarkRunner, RunnerStats execution + build/executable reuse + isolation
-    ShardScheduler, assign_shards sharded process-pool dispatch (jobs=N)
-    Coordinator, ClusterScheduler multi-host cluster dispatch (cluster=...)
+    BenchmarkRunner, RunnerStats in-process execution + build/executable reuse
     RunResult, ResultStore       versioned records, JSONL log + latest pointer
     TraceSpec, generate_trace    deterministic serving load profiles
     save_spec, load_spec         recorded traces (trace="file:PATH")
     percentile, latency_summary  shared latency-distribution helpers
 """
-from repro.runner.cluster import (ClusterScheduler, Coordinator,
-                                  parse_cluster_spec)
 from repro.runner.latency import latency_summary, percentile
-from repro.runner.pool import ShardScheduler, assign_shards, rank_groups
 from repro.runner.results import SCHEMA_VERSION, ResultStore, RunResult
 from repro.runner.runner import (BenchmarkRunner, RunnerStats,
                                  dryrun_cell_subprocess)
@@ -25,9 +20,7 @@ from repro.runner.traces import (PROFILES, Request, TraceSpec, load_spec,
 from repro.runner.traces import generate as generate_trace
 
 __all__ = ["Scenario", "ScenarioMatrix", "MODES", "SERVE_MODES", "TASKS",
-           "STEP_TASKS", "BenchmarkRunner", "RunnerStats", "ShardScheduler",
-           "assign_shards", "rank_groups", "Coordinator", "ClusterScheduler",
-           "parse_cluster_spec", "RunResult", "ResultStore", "SCHEMA_VERSION",
-           "dryrun_cell_subprocess", "PROFILES", "Request", "TraceSpec",
-           "generate_trace", "save_spec", "load_spec", "percentile",
-           "latency_summary"]
+           "STEP_TASKS", "BenchmarkRunner", "RunnerStats", "RunResult",
+           "ResultStore", "SCHEMA_VERSION", "dryrun_cell_subprocess",
+           "PROFILES", "Request", "TraceSpec", "generate_trace", "save_spec",
+           "load_spec", "percentile", "latency_summary"]

@@ -3,15 +3,12 @@
 A loadgen cell replays a (shard of a) deterministic trace against the
 continuous-batching serve engine at a scaled *offered load*, so a matrix
 sweeping ``loads=(0.5, 1.0, 2.0, 4.0)`` measures a TTFT/p99-vs-load
-curve; sweeping ``splits=("0/2", "1/2")`` across cluster workers replays
-trace shards against as many engines as the pool has workers — the
-N-workers-x-M-engines fleet measurement, dispatched through the same
-JSONL protocol as every other cell.
+curve; sweeping ``splits=("0/2", "1/2")`` replays each trace shard as a
+cell of its own.
 
 Both transforms act on the generated ``Request`` list, never on the
 spec: the prompt tokens stay a pure function of (trace spec, params), so
-shard digests are stable and a sharded run's union equals the unsharded
-trace.
+shard digests are stable and the shards' union equals the whole trace.
 
 ``find_knee`` post-processes a measured curve: offered load is swept up,
 throughput saturates, and the knee is the last point whose marginal
@@ -133,8 +130,8 @@ def shard_requests(requests: List[Request], split: str) -> List[Request]:
     """Shard ``i/n``: keep every n-th request by rid order, starting at i.
 
     Deterministic in the request ids alone (not list order, not arrival
-    times), so the same split expression names the same shard on every
-    worker, and the n shards partition the trace exactly.
+    times), so the same split expression always names the same shard, and
+    the n shards partition the trace exactly.
     """
     if not split:
         return requests

@@ -16,37 +16,12 @@
     error        str?   exception text when status == "error"
     extra        dict   free-form payload (dry-run cells, hook params, ...)
 
-Well-known ``extra`` keys written by the runner (still schema v1 — readers
-must tolerate their absence):
-
-    extra["isolated"]      bool   measured in a worker subprocess
-                                  (``isolate=True``, sharded, or cluster
-                                  dispatch)
-    extra["shard"]         int    worker index that ran this scenario under
-                                  sharded dispatch (``run_matrix(jobs=N)``)
-    extra["host"]          str    registered host id of the cluster worker
-                                  that ran this scenario under cluster
-                                  dispatch (``run_matrix(cluster=...)``,
-                                  see ``repro.runner.cluster``): the
-                                  worker's ``--host`` flag, ``localK`` for
-                                  ``cluster="local:N"`` workers, or
-                                  ``<hostname>-<pid>`` by default.  Also
-                                  set on the error record of a cell that
-                                  was in flight on a worker that died.
-                                  Cluster workers' build/compile counters
-                                  are delta-merged into the parent
-                                  ``RunnerStats`` exactly like pool
-                                  workers' (no per-record snapshot).
-    extra["worker_stats"]  dict   the isolated worker's ``RunnerStats``
-                                  snapshot (model builds / compiles that
-                                  happened out-of-process)
-
 Provenance stamps (``repro.telemetry.provenance``, still schema v1): the
-runner stamps EVERY record — ok and error, every transport — with the
-environment that produced it, so a result history can be grouped into
-comparable series (``repro.telemetry.history`` keys baselines and drift
-detection on them).  Worker-side stamps win (correct host); dispatchers
-only backstop records workers never produced (dead-worker errors):
+runner stamps EVERY record — ok and error — with the environment that
+produced it, so a result history can be grouped into comparable series
+(``repro.telemetry.history`` keys baselines and drift detection on
+them).  These stay optional ``extra`` keys of schema v1, so readers must
+tolerate their absence:
 
     extra["prov_commit"]   str    git commit sha of the benchmarked tree
                                   (``$REPRO_COMMIT`` overrides when the
@@ -63,15 +38,9 @@ run was traced — a ``Tracer`` was passed to the runner or
 ``benchmarks.run --trace-out`` was used):
 
     extra["span_trace"]    str    trace id of the ``run_matrix`` call this
-                                  record was measured in (one id per call,
-                                  shared across coordinator and workers)
+                                  record was measured in (one id per call)
     extra["span_cell"]     str    span id of the cell span that timed this
-                                  record (worker-side under pool/cluster
-                                  dispatch)
-    extra["span_dispatch"] str    span id of the dispatcher-side dispatch
-                                  slot (pool/cluster transports only) —
-                                  the worker's cell spans nest under it in
-                                  the exported Chrome trace
+                                  record
 
 Matrix-expansion annotations:
 
@@ -111,10 +80,8 @@ Every execution also feeds the process-wide metrics registry
 ``{"fleet_metrics": 1, "ts", "counters", "gauges", "histograms"}``
 snapshot in ``results/fleet_status.json`` and as Prometheus text in
 ``results/fleet_metrics.prom``).  Registry counters are *execution*
-counts, not record counts — the pool's measurement fence warms cells
-with an unfenced pass, so ``fleet_cells_total`` can exceed the number
-of records; histograms cross process boundaries as count/sum only
-(percentiles are always measuring-process-local).
+counts, not record counts: a cell run with ``record=False`` counts in
+``fleet_cells_total`` but leaves no record.
 
 Serving cells (``task="serve"``, the continuous-batching engine in
 ``repro.launch.serve``) additionally carry the latency-distribution
@@ -144,7 +111,8 @@ decode latencies* (not step times) and ``runs`` is the request count:
                                   into a measured width at expansion time
                                   (``runner/loadgen.auto_slots``)
     extra["tokens"]        list   generated tokens per request, rid order —
-                                  the serial-vs-sharded determinism witness
+                                  the determinism witness across admission
+                                  policies and replays
     extra["tokens_digest"] str    sha256 of extra["tokens"]
     extra["prompt_len_p50"|"prompt_len_p95"]         prompt-length
                                   percentiles of the replayed trace (mixed
@@ -268,11 +236,10 @@ file, log beside it as ``*.jsonl`` — the layout ``core.regression.MetricStore`
 sits on, keeping its historical single-file format readable).
 
 Concurrency: one store file set may be appended to by several processes at
-once (the sharded ``run_matrix`` path records from parent threads while CI
-sweeps in other processes share the same store).  Log appends are a single
-``O_APPEND`` write, the latest pointer is advanced under an exclusive lock
-file with a read-merge-replace cycle, and ``history()`` skips (and counts)
-torn lines left by a writer killed mid-append.
+once (CI sweeps in separate processes share the same store).  Log appends
+are a single ``O_APPEND`` write, the latest pointer is advanced under an
+exclusive lock file with a read-merge-replace cycle, and ``history()``
+skips (and counts) torn lines left by a writer killed mid-append.
 """
 from __future__ import annotations
 

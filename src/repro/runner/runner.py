@@ -11,8 +11,9 @@ Responsibilities (previously hand-rolled per ``benchmarks/*`` script):
   - **compiled executables** (jitted step + live threaded args) are cached
     per scenario, so re-measuring the same cell (regression CI, bisection)
     never re-jits or re-compiles;
-* optional **subprocess isolation** per scenario (fault containment for
-  crashy cells, the ``launch/dryrun`` idiom) via ``repro.runner.worker``;
+* run every cell in the calling process — a chip belongs to one
+  process, so no cell is ever handed to a worker — with per-cell fault
+  containment (a failing cell becomes an error record);
 * emit a versioned ``RunResult`` per execution into a ``ResultStore``;
 * own the **derived** (compile-only dry-run) path with the same store-level
   caching, so figures that share a cell pay for one subprocess, not N.
@@ -31,39 +32,18 @@ import tempfile
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-from repro.core.harness import (Measurement, RegressionHook, measure,
-                                measure_eager, prepare)
-from repro.core.suite import Benchmark, Built, build_arch, get_benchmark
+from repro.core.harness import RegressionHook, measure, measure_eager, prepare
+from repro.core.suite import Built, build_arch, get_benchmark
 from repro.fleet.metrics import registry as metrics_registry
 from repro.profiler.attribution import attribute, cost_for_executable
 from repro.profiler.timeline import Timeline, device_memory_stats
 from repro.runner.latency import percentile
-from repro.runner.pool import ShardScheduler, _subprocess_env
 from repro.runner.traces import cache_len_bound, spec_for_scenario
 from repro.runner.traces import generate as generate_trace
 from repro.runner.results import ResultStore, RunResult
 from repro.runner.scenario import Scenario, ScenarioMatrix, select_scenarios
 from repro.telemetry.provenance import stamp as stamp_provenance
 from repro.telemetry.spans import NULL_TRACER, Tracer, group_label
-
-
-def _check_in_process(*, jobs: int = 0, cluster: str = "",
-                      isolate: bool = False) -> None:
-    """Refuse multi-process dispatch on a TPU backend.  A chip belongs to
-    one process: this one holds it as soon as it has touched JAX, so a
-    pool, cluster or isolated worker that needs the chip would fail or
-    hang.  On a TPU, run cells in process."""
-    what = [name for name, on in (("jobs>1", jobs and jobs > 1),
-                                  ("cluster", bool(cluster)),
-                                  ("isolate", isolate)) if on]
-    if not what:
-        return
-    import jax
-    if jax.default_backend() == "tpu":
-        raise RuntimeError(
-            f"BenchmarkRunner: {', '.join(what)} starts worker processes "
-            f"that need the TPU this process holds; on a TPU run cells in "
-            f"process (no jobs, cluster or isolate)")
 
 
 @dataclasses.dataclass
@@ -80,15 +60,6 @@ class RunnerStats:
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
 
-    def merge(self, other) -> "RunnerStats":
-        """Field-wise add another stats snapshot (RunnerStats or dict) —
-        how worker-subprocess counts become visible in the parent."""
-        d = other.to_dict() if isinstance(other, RunnerStats) else dict(other or {})
-        for f in dataclasses.fields(self):
-            if d.get(f.name):
-                setattr(self, f.name, getattr(self, f.name) + int(d[f.name]))
-        return self
-
 
 @dataclasses.dataclass
 class _ExecEntry:
@@ -101,9 +72,7 @@ class _ExecEntry:
 class BenchmarkRunner:
     def __init__(self, store: Optional[ResultStore] = None, *,
                  runs: int = 5, warmup: int = 1, compile_warmup: int = 3,
-                 reuse: bool = True, isolate: bool = False, jobs: int = 0,
-                 measure_fence: bool = True, profile: bool = False,
-                 cluster: str = "", steal: bool = True,
+                 reuse: bool = True, profile: bool = False,
                  tracer: Optional[Tracer] = None,
                  coverage: bool = False):
         self.store = store
@@ -115,32 +84,16 @@ class BenchmarkRunner:
         # re-measure and break baseline comparability in regression CI
         self.compile_warmup = compile_warmup
         self.reuse = reuse
-        self.isolate = isolate
-        # default shard count for run_matrix (CLI --jobs); <=1 means the
-        # serial in-process path.  measure_fence serializes the workers'
-        # timed loops (comparable per-cell numbers, what regression CI
-        # wants); throughput-only sweeps may turn it off
-        self.jobs = jobs
-        self.measure_fence = measure_fence
-        # default cluster spec for run_matrix (CLI --cluster): "local:N"
-        # spawns N localhost socket workers, "HOST:PORT" binds the
-        # coordinator there for externally-launched workers (see
-        # repro.runner.cluster); "" means no cluster dispatch.  steal
-        # picks dynamic group stealing vs static LPT for the single-host
-        # pool (the cluster is always dynamic)
-        self.cluster = cluster
-        self.steal = steal
         # measured profiling (src/repro/profiler/): per-step phase
         # timelines + op-class attribution under extra["prof_*"]; per-call
         # override via run(..., profile=...)
         self.profile = profile
         # span tracing (src/repro/telemetry/): an enabled Tracer records
-        # matrix -> group -> cell -> phase spans and stitches worker-side
-        # spans under their dispatch span via the job protocol; the
-        # default NULL_TRACER makes every span site a cheap no-op
+        # matrix -> group -> cell -> phase spans; the default NULL_TRACER
+        # makes every span site a cheap no-op
         self.tracer = tracer or NULL_TRACER
-        # API-surface coverage annotations (opt-in, serial in-process step
-        # cells only): trace each scenario's step once through
+        # API-surface coverage annotations (opt-in, step cells only):
+        # trace each scenario's step once through
         # core.coverage.jaxpr_primitives and attach extra["cov_*"] counts;
         # the process-wide union feeds the metrics-snapshot gauge.  The
         # trace is cached per scenario, so re-measures pay nothing.
@@ -165,41 +118,6 @@ class BenchmarkRunner:
         # the attribution AOT compile is paid once per executable, not per
         # profiled re-measure
         self._prof_costs: Dict[Any, Any] = {}
-        self._pool: Optional[ShardScheduler] = None
-        self._cluster: Optional[Any] = None   # ClusterScheduler, lazy
-        _check_in_process(jobs=jobs, cluster=cluster, isolate=isolate)
-
-    def close(self) -> None:
-        """Shut down the persistent shard workers and the cluster
-        coordinator + its local workers (no-op when serial)."""
-        if self._pool is not None:
-            self._pool.close()
-            self._pool = None
-        if self._cluster is not None:
-            self._cluster.close()
-            self._cluster = None
-
-    def cluster_worker_pids(self) -> List[int]:
-        """PIDs of the locally-spawned cluster workers (``cluster=
-        "local:N"``), empty when no cluster is active or it binds for
-        external workers — the smoke gate's no-orphans check."""
-        return [] if self._cluster is None else self._cluster.worker_pids()
-
-    def worker_pids(self) -> List[int]:
-        """PIDs of every worker subprocess this runner has live — the
-        ``--jobs`` shard pool plus local cluster workers.  The no-orphans
-        gate: after ``close()`` each of these must be dead."""
-        pids: List[int] = []
-        if self._pool is not None:
-            pids.extend(self._pool.worker_pids())
-        pids.extend(self.cluster_worker_pids())
-        return pids
-
-    def __del__(self):
-        try:
-            self.close()
-        except Exception:
-            pass
 
     # ---- build / executable caches -------------------------------------
 
@@ -260,14 +178,10 @@ class BenchmarkRunner:
         module, synchronous dispatch) and record ``prof_skipped`` instead.
 
         ``extra`` is merged into the result's extras (ok or error) —
-        the dispatch layers use it to attach matrix-expansion context
+        ``run_matrix`` uses it to attach matrix-expansion context
         (e.g. ``slots_fallback``) to the record before it is stored.
         """
         prof = self.profile if profile is None else profile
-        if self.isolate:
-            return self._run_isolated(scenario, hook=hook, runs=runs,
-                                      warmup=warmup, record=record,
-                                      profile=prof, extra=extra)
         if scenario.task in ("serve", "loadgen"):
             return self._run_serve(scenario, hook=hook, record=record,
                                    profile=prof, extra=extra)
@@ -347,7 +261,7 @@ class BenchmarkRunner:
 
     def _finalize(self, rr: RunResult, cell_span: Any,
                   extra: Optional[Dict[str, Any]], record: bool) -> RunResult:
-        """Shared result epilogue: merge dispatch-provided extras, stamp
+        """Shared result epilogue: merge matrix-provided extras, stamp
         span ids + provenance, record."""
         if extra:
             rr.extra.update(extra)
@@ -390,14 +304,13 @@ class BenchmarkRunner:
         """One tuning candidate (``task="kernel"``): decode the candidate
         id from the ``arch`` axis (``repro.tuning.space``), jit its
         ops-layer call, and measure it under the standard ``measure()``
-        protocol — so a sweep's cells dispatch, shard, fence, and record
-        exactly like model cells.  The candidate's identity lands under
+        protocol — so a sweep's cells run and record exactly like model
+        cells.  The candidate's identity lands under
         the well-known ``tuning_*`` extras (``runner/results.py``).
 
         The compiled candidate is cached in ``self._execs`` like a model
-        executable: re-measuring a candidate (regression CI, the pool's
-        fenced re-run) hits the cache, and the pool worker's ledger
-        accounting stays correct."""
+        executable: re-measuring a candidate (regression CI) hits the
+        cache."""
         from repro.tuning import space as tuning_space
         t0 = time.perf_counter()
         self.stats.scenarios_run += 1
@@ -683,59 +596,22 @@ class BenchmarkRunner:
                    hooks: Optional[Dict[str, RegressionHook]] = None,
                    runs: Optional[int] = None,
                    warmup: Optional[int] = None,
-                   jobs: Optional[int] = None,
-                   cluster: Optional[str] = None,
                    profile: Optional[bool] = None) -> List[RunResult]:
-        """Run every scenario of the matrix; hooks are keyed by benchmark
-        name ("arch/task") or full scenario name.
+        """Run every scenario of the matrix in this process, in matrix
+        order; hooks are keyed by benchmark name ("arch/task") or full
+        scenario name.  ``profile`` (default: the runner's setting)
+        profiles every cell.
 
-        ``jobs=N`` (default: the runner's ``jobs`` setting) shards the
-        selected scenarios across N persistent worker subprocesses, grouped
-        by build_key so each worker keeps its caches hot (see
-        ``repro.runner.pool``); results come back in matrix order with
-        ``extra["shard"]`` set.  ``jobs<=1`` is the serial in-process path.
-        ``cluster`` (default: the runner's setting; overrides ``jobs``)
-        dispatches across socket-connected workers instead —
-        ``"local:N"`` spins up N localhost worker subprocesses,
-        ``"HOST:PORT"`` binds a coordinator for workers launched elsewhere
-        with ``worker --connect`` (see ``repro.runner.cluster``); results
-        carry ``extra["host"]``.  ``profile`` (default: the runner's
-        setting) profiles every cell — under sharded/cluster dispatch the
-        flag rides in each worker job, so profiled sweeps dispatch exactly
-        like unprofiled ones.
-
-        An enabled ``tracer`` records ONE trace per call regardless of
-        transport: a matrix root span, a group span per build key, and a
-        cell span per scenario with its phase children — worker-side
-        spans ride back in the job protocol and stitch under their
-        dispatch span.
+        An enabled ``tracer`` records ONE trace per call: a matrix root
+        span, a group span per build key, and a cell span per scenario
+        with its phase children.
         """
         scenarios = self.select(matrix)
-        jobs = self.jobs if jobs is None else jobs
-        cluster = self.cluster if cluster is None else cluster
-        _check_in_process(jobs=jobs, cluster=cluster)
         extras = self._matrix_extras(matrix, scenarios)
         tr = self.tracer
         if tr.enabled:
             tr.begin_trace()
-        transport = ("cluster:" + cluster if cluster and scenarios else
-                     f"jobs={jobs}" if jobs and jobs > 1 and scenarios else
-                     "serial")
-        with tr.span("matrix", kind="matrix", cells=len(scenarios),
-                     transport=transport) as root:
-            if cluster and scenarios:
-                return self._run_clustered(scenarios, hooks=hooks, runs=runs,
-                                           warmup=warmup, cluster=cluster,
-                                           profile=profile,
-                                           trace_parent=root, extras=extras)
-            if jobs and jobs > 1 and scenarios:
-                # even a single selected cell goes through the pool: the
-                # caller opted into worker fault containment and shard
-                # metadata
-                return self._run_sharded(scenarios, hooks=hooks, runs=runs,
-                                         warmup=warmup, jobs=jobs,
-                                         profile=profile,
-                                         trace_parent=root, extras=extras)
+        with tr.span("matrix", kind="matrix", cells=len(scenarios)) as root:
             out = []
             for sc in scenarios:
                 hook = (hooks or {}).get(sc.name) or (hooks or {}).get(sc.bench)
@@ -751,8 +627,7 @@ class BenchmarkRunner:
                        scenarios: List[Scenario]) -> Dict[str, Dict[str, Any]]:
         """Per-cell extras derived from matrix expansion (currently the
         ``slots_fallback`` staleness marker from ``slots="auto"``
-        resolution) — attached to each result before it is recorded,
-        on every transport."""
+        resolution) — attached to each result before it is recorded."""
         fb = getattr(matrix, "slots_fallback", None)
         fb = fb() if callable(fb) else {}
         if not fb:
@@ -763,10 +638,9 @@ class BenchmarkRunner:
     @staticmethod
     def _stitch_serial_groups(tr: Tracer, scenarios: List[Scenario],
                               results: List[RunResult], root: Any) -> None:
-        """Serial cells interleave across build keys in matrix order, so
-        their group spans are synthesized after the loop from the
-        recorded cell spans (pool/cluster dispatchers open group spans
-        live instead)."""
+        """Cells interleave across build keys in matrix order, so their
+        group spans are synthesized after the loop from the recorded cell
+        spans."""
         by_key: Dict[Tuple, List[str]] = {}
         for sc, rr in zip(scenarios, results):
             sid = rr.extra.get("span_cell")
@@ -774,142 +648,6 @@ class BenchmarkRunner:
                 by_key.setdefault(sc.build_key(), []).append(sid)
         for bkey, ids in by_key.items():
             tr.group("group:" + group_label(bkey), ids, parent=root)
-
-    def _run_sharded(self, scenarios: List[Scenario], *,
-                     hooks: Optional[Dict[str, RegressionHook]],
-                     runs: Optional[int], warmup: Optional[int],
-                     jobs: int,
-                     profile: Optional[bool] = None,
-                     trace_parent: Any = None,
-                     extras: Optional[Dict[str, Dict[str, Any]]] = None
-                     ) -> List[RunResult]:
-        """Dispatch a scenario batch to the persistent shard pool; the pool
-        (and its workers' warm caches) lives until ``close()``."""
-        if self._pool is not None and self._pool.jobs != jobs:
-            self._pool.close()
-            self._pool = None
-        if self._pool is None:
-            self._pool = ShardScheduler(jobs, runs=self.runs,
-                                        warmup=self.warmup,
-                                        compile_warmup=self.compile_warmup,
-                                        reuse=self.reuse,
-                                        measure_fence=self.measure_fence)
-        record = self.store.append if self.store is not None else None
-        prof = self.profile if profile is None else profile
-        results, run_stats = self._pool.run(scenarios, hooks=hooks,
-                                            runs=runs, warmup=warmup,
-                                            profile=prof,
-                                            on_result=record,
-                                            steal=self.steal,
-                                            tracer=self.tracer,
-                                            trace_parent=trace_parent,
-                                            extras=extras)
-        self.stats.merge(run_stats)
-        return results
-
-    def _run_clustered(self, scenarios: List[Scenario], *,
-                       hooks: Optional[Dict[str, RegressionHook]],
-                       runs: Optional[int], warmup: Optional[int],
-                       cluster: str,
-                       profile: Optional[bool] = None,
-                       trace_parent: Any = None,
-                       extras: Optional[Dict[str, Dict[str, Any]]] = None
-                       ) -> List[RunResult]:
-        """Dispatch a scenario batch to the cluster coordinator; the
-        coordinator — its worker connections, and for ``local:N`` the
-        spawned worker subprocesses with their warm caches — lives until
-        ``close()``, like the single-host pool."""
-        from repro.runner.cluster import ClusterScheduler
-        if self._cluster is not None and self._cluster.spec != cluster:
-            self._cluster.close()
-            self._cluster = None
-        if self._cluster is None:
-            self._cluster = ClusterScheduler(
-                cluster, runs=self.runs, warmup=self.warmup,
-                compile_warmup=self.compile_warmup, reuse=self.reuse,
-                measure_fence=self.measure_fence)
-        record = self.store.append if self.store is not None else None
-        prof = self.profile if profile is None else profile
-        results, run_stats = self._cluster.run(scenarios, hooks=hooks,
-                                               runs=runs, warmup=warmup,
-                                               profile=prof,
-                                               on_result=record,
-                                               tracer=self.tracer,
-                                               trace_parent=trace_parent,
-                                               extras=extras)
-        self.stats.merge(run_stats)
-        return results
-
-    # ---- subprocess isolation -------------------------------------------
-
-    def _run_isolated(self, scenario: Scenario, *,
-                      hook: Optional[RegressionHook] = None,
-                      runs: Optional[int] = None,
-                      warmup: Optional[int] = None,
-                      record: bool = True, timeout: int = 1200,
-                      profile: bool = False,
-                      extra: Optional[Dict[str, Any]] = None) -> RunResult:
-        """One scenario in its own interpreter: a crash (OOM, segfault in a
-        kernel, ...) becomes an error record instead of killing the sweep.
-
-        The full measurement config (runs/warmup/compile-warmup/reuse) is
-        forwarded so the isolated measurement follows the same protocol as
-        the in-process path (comparable as a regression baseline), and the
-        worker's ``RunnerStats`` come back in the payload and are merged —
-        out-of-process builds/compiles count like in-process ones."""
-        t0 = time.perf_counter()
-        fd, out = tempfile.mkstemp(suffix=".json", prefix="repro_runner_")
-        os.close(fd)
-        cmd = [sys.executable, "-m", "repro.runner.worker",
-               "--scenario", json.dumps(scenario.to_dict()),
-               "--runs", str(runs or self.runs),
-               "--warmup", str(self.warmup if warmup is None else warmup),
-               "--compile-warmup", str(self.compile_warmup),
-               "--json", out]
-        if not self.reuse:
-            cmd.append("--no-reuse")
-        if profile:
-            cmd.append("--profile")
-        if hook is not None:
-            cmd += ["--slowdown-s", str(hook.slowdown_s),
-                    "--leak-bytes", str(hook.leak_bytes)]
-        try:
-            r = subprocess.run(cmd, env=_subprocess_env(), capture_output=True,
-                               text=True, timeout=timeout)
-            if r.returncode == 0 and os.path.getsize(out):
-                with open(out) as f:
-                    payload = json.load(f)
-                rr = RunResult.from_dict(payload["result"])
-                worker_stats = payload.get("stats") or {}
-                rr.wall_s = time.perf_counter() - t0
-                rr.extra["isolated"] = True
-                rr.extra["worker_stats"] = worker_stats
-                self.stats.merge(worker_stats)
-            else:
-                self.stats.scenarios_run += 1
-                self.stats.errors += 1
-                rr = RunResult.from_error(
-                    scenario, f"worker exit {r.returncode}: {r.stderr[-500:]}",
-                    wall_s=time.perf_counter() - t0)
-        except subprocess.TimeoutExpired:
-            self.stats.scenarios_run += 1
-            self.stats.errors += 1
-            rr = RunResult.from_error(scenario, f"worker timeout after {timeout}s",
-                                      wall_s=time.perf_counter() - t0)
-        finally:
-            if os.path.exists(out):
-                os.remove(out)
-        if extra:
-            rr.extra.update(extra)
-        # the worker stamped its own provenance (correct host/backend);
-        # setdefault only fills locally-created error records
-        stamp_provenance(rr)
-        # single-shot worker: its registry dies with it, so the parent
-        # counts the execution (unlike the pool/cluster delta-merge)
-        metrics_registry().record_result(rr)
-        if record and self.store is not None:
-            self.store.append(rr)
-        return rr
 
     # ---- derived (compile-only dry-run) path -----------------------------
 
@@ -953,6 +691,21 @@ class BenchmarkRunner:
     def dryrun_cells(self, cells: Sequence[Tuple[str, str]], *,
                      multi_pod: bool = False) -> List[Dict[str, Any]]:
         return [self.run_dryrun(a, s, multi_pod=multi_pod) for a, s in cells]
+
+
+def _src_dir() -> str:
+    import repro
+    pkg = (repro.__file__ and os.path.dirname(repro.__file__)) or \
+        list(repro.__path__)[0]
+    return os.path.dirname(os.path.abspath(pkg))
+
+
+def _subprocess_env() -> Dict[str, str]:
+    env = dict(os.environ)
+    src = _src_dir()
+    env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
+                               if env.get("PYTHONPATH") else "")
+    return env
 
 
 def dryrun_cell_subprocess(arch: str, shape: str, *, multi_pod: bool = False,

@@ -27,8 +27,7 @@ STEP_TASKS = ("train", "infer_prefill", "infer_decode")
 #: all tasks: the step tasks, the continuous-batching serving workload
 #: (a whole engine run per cell, ``repro.launch.serve``), the
 #: load-generation mode over that same engine (``task="loadgen"``: replay
-#: a trace shard at a scaled offered load — N workers x M engines comes
-#: free from ordinary matrix dispatch), and the kernel micro-bench cells
+#: a trace shard at a scaled offered load), and the kernel micro-bench cells
 #: of the autotuner (``repro.tuning``), whose ``arch`` axis names a
 #: tuning candidate instead of a registry arch
 TASKS = STEP_TASKS + ("serve", "loadgen", "kernel")
@@ -204,21 +203,19 @@ class Scenario:
         """Cache key for the arch build (model + params) this scenario needs.
 
         Serve cells extend the key with ("serve", slots): the compiled
-        decode executable is shaped by the slot count, so sharding by
-        build_key keeps each worker's serve-engine cache hot.  The trace
-        profile is deliberately NOT in the key — it changes the replayed
-        load, never what gets built or compiled, so traces of one
-        (arch, slots) group should land on one worker and share engines.
+        decode executable is shaped by the slot count.  The trace profile
+        is deliberately NOT in the key — it changes the replayed load,
+        never what gets built or compiled, so traces of one (arch, slots)
+        group share one engine.  The key also names the matrix trace's
+        group spans.
         """
         base = (self.arch, self.dtype, self.mode in MODE_OVERRIDES and self.mode)
         if self.task in ("serve", "loadgen"):
             # loadgen shares the serve group: same slots -> same compiled
-            # decode executable and cached engine on whichever worker runs it
+            # decode executable and cached engine
             return base + ("serve", self.slots)
         if self.task == "kernel":
-            # one group per candidate: kernel cells share no arch build,
-            # so the scheduler is free to place (and steal) them singly —
-            # the sweep is embarrassingly parallel
+            # one group per candidate: kernel cells share no arch build
             return ("kernel", self.arch, self.dtype)
         return base
 

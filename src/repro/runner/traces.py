@@ -7,8 +7,8 @@ wall seconds: the continuous-batching engine admits a request once its
 ``arrival_step`` has passed, so which requests share slots — and therefore
 the exact tokens generated — depends only on (profile, seed), never on
 host speed.  That is what makes the acceptance invariant possible: the
-same trace produces byte-identical token outputs whether the cell runs
-serially in-process or sharded across worker subprocesses.
+same trace produces byte-identical token outputs whatever the cell's
+place in the matrix, the admission policy, or the replay.
 
 Arrival profiles (``PROFILES``):
 
@@ -175,10 +175,10 @@ def generate(spec: TraceSpec, vocab: int) -> List[Request]:
 
     Each component (lengths, arrivals, budgets, prompt content) draws
     from its own seeded stream in a fixed order, so a spec is a pure
-    function of its fields — the worker subprocess regenerating the trace
-    from the scenario gets the same requests the in-process path would,
-    and a captured spec (explicit lengths/arrivals/budgets) regenerates
-    the exact prompts of the run it was captured from.
+    function of its fields — every regeneration of the trace from the
+    scenario gets the same requests, and a captured spec (explicit
+    lengths/arrivals/budgets) regenerates the exact prompts of the run it
+    was captured from.
     """
     n = spec.requests
     lens = _draw_lengths(spec)
@@ -254,7 +254,7 @@ def cache_len_bound(requests: Sequence[Request], *, prefix: int = 0) -> int:
 
 def tokens_by_rid(requests: Sequence[Request]) -> List[List[int]]:
     """Generated tokens in rid order — the canonical output view used for
-    the serial-vs-sharded determinism check."""
+    the determinism check."""
     return [list(r.out) for r in sorted(requests, key=lambda r: r.rid)]
 
 
@@ -324,10 +324,8 @@ def spec_for_scenario(scenario, *, seed: Optional[int] = None) -> TraceSpec:
 
     ``trace="file:PATH"`` replays a recorded spec: the file defines the
     whole workload (request count, prompt lengths, budgets, seed) and the
-    scenario's ``batch``/``seq`` axes are advisory labels only.  The file
-    must exist on the host that RUNS the cell — under cluster dispatch
-    that is the worker, so recorded traces need a shared or replicated
-    path.  Otherwise ``trace`` names a generative profile
+    scenario's ``batch``/``seq`` axes are advisory labels only.
+    Otherwise ``trace`` names a generative profile
     (``"arrival[+length]"``, e.g. ``"bursty+bimodal"``): batch -> request
     count, seq -> base prompt length, output budget derived from the
     prompt length."""

@@ -1,9 +1,7 @@
-"""Observability for the benchmark runner: distributed span tracing,
-provenance stamping, and provenance-keyed result history.
+"""Observability for the benchmark runner: span tracing, provenance
+stamping, and provenance-keyed result history.
 
-- ``spans``       low-overhead thread-safe ``Tracer``; span ids ride the
-                  JSONL job protocol so worker spans stitch under their
-                  coordinator dispatch span (one trace per ``run_matrix``)
+- ``spans``       low-overhead ``Tracer`` (one trace per ``run_matrix``)
 - ``export``      Chrome trace-event JSON (Perfetto) + terminal flame text
 - ``provenance``  ``prov_*`` extras: commit sha/dirty, backend, host,
                   jax/python versions, stamped on every ``RunResult``

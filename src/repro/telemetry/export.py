@@ -2,11 +2,11 @@
 flame summary for terminals.
 
 The Chrome format is the ``traceEvents`` array flavour: one ``"X"``
-(complete) event per span with microsecond ``ts``/``dur``, one process
-lane (``pid``) per span ``proc`` (coordinator, shard0.., local0.. /
-remote hosts), and ``"M"`` metadata events naming the lanes.  Span ids,
-parents and attrs ride in ``args`` so the nesting test and the smoke
-gate can reconstruct the tree from the file alone.
+(complete) event per span with microsecond ``ts``/``dur``, all on one
+lane (every span comes from the process that runs the cells), and an
+``"M"`` metadata event naming it.  Span ids, parents and attrs ride in
+``args`` so the nesting test and the smoke gate can reconstruct the
+tree from the file alone.
 """
 from __future__ import annotations
 
@@ -23,29 +23,10 @@ def _as_dicts(spans: Iterable) -> List[Dict[str, Any]]:
 
 def chrome_trace(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
     """Convert ``Tracer.export()`` span dicts to a Chrome trace dict."""
-    spans = _as_dicts(spans)
-    # stable small pids: coordinator first, then lanes by first appearance
-    pids: Dict[str, int] = {}
-    for sp in spans:
-        proc = str(sp.get("proc", "?"))
-        if proc not in pids:
-            pids[proc] = 1 + len(pids) if proc != "coordinator" else 0
-    if "coordinator" in pids and pids["coordinator"] != 0:
-        # renumber so the coordinator lane is pid 0 at the top
-        order = ["coordinator"] + [p for p in pids if p != "coordinator"]
-        pids = {p: i for i, p in enumerate(order)}
-    # per-proc compact tids
-    tids: Dict[str, Dict[int, int]] = {}
-    events: List[Dict[str, Any]] = []
-    for proc, pid in pids.items():
-        events.append({"ph": "M", "name": "process_name", "pid": pid,
-                       "tid": 0, "args": {"name": proc}})
-    for sp in spans:
-        proc = str(sp.get("proc", "?"))
-        pid = pids[proc]
-        raw_tid = int(sp.get("tid", 0))
-        lane = tids.setdefault(proc, {})
-        tid = lane.setdefault(raw_tid, len(lane))
+    events: List[Dict[str, Any]] = [
+        {"ph": "M", "name": "process_name", "pid": 0, "tid": 0,
+         "args": {"name": "runner"}}]
+    for sp in _as_dicts(spans):
         args: Dict[str, Any] = {
             "span_id": sp.get("span_id"),
             "parent": sp.get("parent_id"),
@@ -58,8 +39,8 @@ def chrome_trace(spans: Iterable[Dict[str, Any]]) -> Dict[str, Any]:
             "ph": "X",
             "name": str(sp.get("name", "?")),
             "cat": str(sp.get("kind", "span")),
-            "pid": pid,
-            "tid": tid,
+            "pid": 0,
+            "tid": 0,
             "ts": float(sp.get("ts", 0.0)) * 1e6,
             "dur": max(0.0, float(sp.get("dur_s", 0.0))) * 1e6,
             "args": args,
@@ -100,10 +81,7 @@ def flame_summary(spans: Iterable[Dict[str, Any]], *, max_depth: int = 8,
     def emit(sp: Dict[str, Any], depth: int) -> None:
         pad = "  " * depth
         name = str(sp.get("name", "?"))
-        proc = str(sp.get("proc", ""))
-        lane = f" [{proc}]" if proc and proc != "coordinator" else ""
-        lines.append(f"{pad}{name} {_fmt_dur(float(sp.get('dur_s', 0.0)))}"
-                     f"{lane}")
+        lines.append(f"{pad}{name} {_fmt_dur(float(sp.get('dur_s', 0.0)))}")
         if depth + 1 >= max_depth:
             return
         children = kids.get(sp.get("span_id"), [])

@@ -10,10 +10,8 @@ Every result-creating path in the runner stamps these well-known
     prov_jax        jax.__version__
     prov_python     platform.python_version()
 
-Workers stamp in their own process so host/backend reflect where the
-number was actually measured; dispatcher-side stamping uses setdefault
-semantics and only fills records created locally (e.g. worker-death
-error results).
+Stamping uses setdefault semantics: a record that already carries a
+stamp (one loaded from a store, say) keeps it.
 
 Collection is cached per process — two subprocess calls (git) and one
 jax attribute read, once.

@@ -1,33 +1,21 @@
-"""Low-overhead distributed span tracing for the benchmark runner.
+"""Low-overhead span tracing for the benchmark runner.
 
-One trace covers one ``run_matrix`` call regardless of transport.  The
-span hierarchy is::
+One trace covers one ``run_matrix`` call, all of it in the calling
+process.  The span hierarchy is::
 
-    matrix                          (coordinator lane)
+    matrix
       group:<build-key>             (one per build-key group)
-        cell:<scenario>             (serial) or
-        dispatch:<scenario>         (pool / cluster dispatch slot)
-          cell:<scenario>           (worker lane, stitched by trace ctx)
-            build / compile / warm / measure / attribute   (phases)
-              admit.* / decode.* engine phases             (serve only)
+        cell:<scenario>
+          build / compile / warm / measure / attribute   (phases)
+            admit.* / decode.* engine phases             (serve only)
 
-Design constraints:
+``Tracer(enabled=False)`` (the module singleton ``NULL_TRACER``) makes
+``span()`` yield a shared no-op object without allocating; instrumented
+code never branches on anything else.
 
-- **Cheap when off.**  ``Tracer(enabled=False)`` (the module singleton
-  ``NULL_TRACER``) makes ``span()`` yield a shared no-op object without
-  allocating; instrumented code never branches on anything else.
-- **Thread-safe.**  The shard pool drives one thread per worker; spans
-  append under a lock and the implicit parent stack is thread-local.
-- **Wire-friendly.**  A span context is two strings
-  (``{"trace_id", "parent"}``) carried by the JSONL job protocol; a
-  worker builds a private ``Tracer`` seeded with them, runs the cell,
-  and ships ``export()`` back in the result message.  The dispatcher
-  ``ingest()``s those dicts under the worker's lane so the stitched
-  timeline nests worker cells beneath their coordinator dispatch span.
-
-Timestamps are wall-clock (``time.time()``) so same-host processes
-share a base; durations come from paired wall reads, which is plenty at
-the >=microsecond scale of benchmark phases.
+Timestamps are wall-clock (``time.time()``); durations come from paired
+``perf_counter`` reads, which is plenty at the >=microsecond scale of
+benchmark phases.
 """
 from __future__ import annotations
 
@@ -36,9 +24,8 @@ import itertools
 import json
 import os
 import sys
-import threading
 import time
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple, Union
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 __all__ = [
     "Span",
@@ -51,7 +38,7 @@ __all__ = [
 
 
 def _new_prefix() -> str:
-    # unique across processes (pid) and across Tracer instances within a
+    # unique across runs (pid) and across Tracer instances within a
     # process (urandom); span ids are then "<prefix>.<counter>"
     return f"{os.getpid():x}-{os.urandom(3).hex()}"
 
@@ -64,18 +51,15 @@ def group_label(build_key: Tuple) -> str:
 class Span:
     """One timed region.  Mutable until :meth:`Tracer.finish` seals it."""
 
-    __slots__ = ("name", "span_id", "parent_id", "kind", "proc", "tid",
-                 "ts", "dur_s", "attrs", "_t0")
+    __slots__ = ("name", "span_id", "parent_id", "kind", "ts", "dur_s",
+                 "attrs", "_t0")
 
     def __init__(self, name: str, span_id: str, parent_id: Optional[str],
-                 kind: str, proc: str, tid: int, ts: float,
-                 attrs: Optional[Dict[str, Any]] = None):
+                 kind: str, ts: float, attrs: Optional[Dict[str, Any]] = None):
         self.name = name
         self.span_id = span_id
         self.parent_id = parent_id
         self.kind = kind
-        self.proc = proc
-        self.tid = tid
         self.ts = ts              # wall-clock start (time.time())
         self.dur_s = 0.0
         self.attrs = attrs or {}
@@ -91,8 +75,6 @@ class Span:
             "span_id": self.span_id,
             "parent_id": self.parent_id,
             "kind": self.kind,
-            "proc": self.proc,
-            "tid": self.tid,
             "ts": self.ts,
             "dur_s": self.dur_s,
         }
@@ -109,8 +91,6 @@ class _NoopSpan:
     span_id = ""
     parent_id = None
     kind = ""
-    proc = ""
-    tid = 0
     ts = 0.0
     dur_s = 0.0
     attrs: Dict[str, Any] = {}
@@ -146,24 +126,20 @@ class _SpanCtx:
 
 
 class Tracer:
-    """Collects spans for one process's view of a trace.
+    """Collects the spans of a trace.
 
     ``enabled=False`` turns every entry point into a near-free no-op so
     the instrumented hot path costs one attribute load + branch.
     """
 
-    def __init__(self, *, enabled: bool = True, trace_id: Optional[str] = None,
-                 proc: str = "coordinator", root_parent: Optional[str] = None):
+    def __init__(self, *, enabled: bool = True):
         self.enabled = enabled
-        self.proc = proc
-        self.trace_id = trace_id or _new_prefix()
-        self.root_parent = root_parent   # default parent when stack empty
+        self.trace_id = _new_prefix()
         self._prefix = _new_prefix()
         self._counter = itertools.count(1)
-        self._lock = threading.Lock()
         self._spans: List[Span] = []
         self._by_id: Dict[str, Span] = {}
-        self._tls = threading.local()
+        self._stack: List[Span] = []   # implicit parents of span()
 
     # -- trace lifecycle ------------------------------------------------
 
@@ -178,67 +154,50 @@ class Tracer:
 
     # -- span creation --------------------------------------------------
 
-    def _stack(self) -> List[Span]:
-        st = getattr(self._tls, "stack", None)
-        if st is None:
-            st = self._tls.stack = []
-        return st
-
     def _next_id(self) -> str:
         return f"{self._prefix}.{next(self._counter)}"
 
     def start(self, name: str, *, kind: str = "span",
               parent: Union[Span, str, None] = None,
               **attrs: Any) -> Union[Span, _NoopSpan]:
-        """Open a span without touching the implicit stack (for async
-        open/close across callbacks, e.g. coordinator dispatch slots)."""
+        """Open a span without touching the implicit stack (closed later
+        by :meth:`finish`)."""
         if not self.enabled:
             return _NOOP
         pid = self._resolve_parent(parent)
-        sp = Span(name, self._next_id(), pid, kind, self.proc,
-                  threading.get_ident(), time.time(), attrs or None)
+        sp = Span(name, self._next_id(), pid, kind, time.time(),
+                  attrs or None)
         sp._t0 = time.perf_counter()
         return sp
 
-    def finish(self, span: Union[Span, _NoopSpan],
-               end_ts: Optional[float] = None) -> None:
+    def finish(self, span: Union[Span, _NoopSpan]) -> None:
+        """Seal a span opened by :meth:`start`."""
         if span is _NOOP or not isinstance(span, Span):
             return
-        if end_ts is not None:
-            span.dur_s = max(0.0, end_ts - span.ts)
-        elif span._t0:
-            span.dur_s = time.perf_counter() - span._t0
-        else:
-            span.dur_s = max(0.0, time.time() - span.ts)
+        span.dur_s = time.perf_counter() - span._t0
         self._record(span)
-        st = self._stack()
-        if st and st[-1] is span:
-            st.pop()
+        if self._stack and self._stack[-1] is span:
+            self._stack.pop()
 
     def span(self, name: str, *, kind: str = "span",
              parent: Union[Span, str, None] = None, **attrs: Any) -> _SpanCtx:
-        """Context manager: open on enter, seal on exit.  Nested calls on
-        the same thread parent to the enclosing span automatically."""
+        """Context manager: open on enter, seal on exit.  Nested calls
+        parent to the enclosing span automatically."""
         if not self.enabled:
             return _SpanCtx(self, _NOOP)
         sp = self.start(name, kind=kind, parent=parent, **attrs)
-        self._stack().append(sp)          # type: ignore[arg-type]
+        self._stack.append(sp)          # type: ignore[arg-type]
         return _SpanCtx(self, sp)
 
     def add(self, name: str, *, ts: float, dur_s: float,
             parent: Union[Span, str, None] = None, kind: str = "phase",
-            tid: Optional[int] = None, **attrs: Any) -> Union[Span, _NoopSpan]:
+            **attrs: Any) -> Union[Span, _NoopSpan]:
         """Record a span retroactively from captured wall timestamps
         (phase events logged by the harness / serve engine)."""
         if not self.enabled:
             return _NOOP
-        pid = self._resolve_parent(parent)
-        ptid = tid
-        if ptid is None:
-            psp = self._by_id.get(pid) if pid else None
-            ptid = psp.tid if psp is not None else threading.get_ident()
-        sp = Span(name, self._next_id(), pid, kind, self.proc, ptid, ts,
-                  attrs or None)
+        sp = Span(name, self._next_id(), self._resolve_parent(parent), kind,
+                  ts, attrs or None)
         sp.dur_s = max(0.0, dur_s)
         self._record(sp)
         return sp
@@ -248,45 +207,13 @@ class Tracer:
             if isinstance(parent, str):
                 return parent
             return getattr(parent, "span_id", None) or None
-        st = self._stack()
-        if st:
-            return st[-1].span_id
-        return self.root_parent
+        return self._stack[-1].span_id if self._stack else None
 
     def _record(self, span: Span) -> None:
-        with self._lock:
-            self._spans.append(span)
-            self._by_id[span.span_id] = span
+        self._spans.append(span)
+        self._by_id[span.span_id] = span
 
-    # -- stitching ------------------------------------------------------
-
-    def context(self, span: Union[Span, _NoopSpan, None] = None
-                ) -> Optional[Dict[str, str]]:
-        """Wire context for a job message: ``{"trace_id", "parent"}``."""
-        if not self.enabled:
-            return None
-        parent = getattr(span, "span_id", "") if span is not None else ""
-        return {"trace_id": self.trace_id, "parent": parent or ""}
-
-    def ingest(self, span_dicts: Optional[Iterable[Dict[str, Any]]],
-               proc: Optional[str] = None) -> int:
-        """Adopt spans exported by a remote process, relabelling their
-        lane to *proc* (the dispatcher knows the worker's identity)."""
-        if not self.enabled or not span_dicts:
-            return 0
-        n = 0
-        for d in span_dicts:
-            if not isinstance(d, dict) or "span_id" not in d:
-                continue
-            sp = Span(str(d.get("name", "?")), str(d["span_id"]),
-                      d.get("parent_id") or None, str(d.get("kind", "span")),
-                      proc or str(d.get("proc", "remote")),
-                      int(d.get("tid", 0)), float(d.get("ts", 0.0)),
-                      dict(d.get("attrs") or {}))
-            sp.dur_s = float(d.get("dur_s", 0.0))
-            self._record(sp)
-            n += 1
-        return n
+    # -- grouping ------------------------------------------------------
 
     def group(self, name: str, child_ids: Sequence[str], *,
               parent: Union[Span, str, None] = None,
@@ -296,8 +223,7 @@ class Tracer:
         are stitched after the fact)."""
         if not self.enabled:
             return _NOOP
-        with self._lock:
-            kids = [self._by_id[c] for c in child_ids if c in self._by_id]
+        kids = [self._by_id[c] for c in child_ids if c in self._by_id]
         if not kids:
             return _NOOP
         t0 = min(k.ts for k in kids)
@@ -311,22 +237,18 @@ class Tracer:
     # -- export ---------------------------------------------------------
 
     def export(self) -> List[Dict[str, Any]]:
-        with self._lock:
-            spans = sorted(self._spans, key=lambda s: s.ts)
-        return [s.to_dict() for s in spans]
+        return [s.to_dict() for s in sorted(self._spans, key=lambda s: s.ts)]
 
     def find(self, span_id: str) -> Optional[Span]:
         return self._by_id.get(span_id)
 
     @property
     def spans(self) -> List[Span]:
-        with self._lock:
-            return list(self._spans)
+        return list(self._spans)
 
     def clear(self) -> None:
-        with self._lock:
-            self._spans.clear()
-            self._by_id.clear()
+        self._spans.clear()
+        self._by_id.clear()
 
 
 NULL_TRACER = Tracer(enabled=False)

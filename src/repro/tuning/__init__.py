@@ -8,8 +8,7 @@ kernels' launch parameters:
 * ``tuning.space``  — per-kernel search spaces: valid, VMEM-bounded
   candidates derived from the input shape, encoded as scenario archs;
 * ``tuning.sweep``  — case expansion into a ``task="kernel"``
-  ``ScenarioMatrix`` dispatched through ``BenchmarkRunner.run_matrix``
-  (parallel under ``jobs=N`` / ``cluster=`` for free) + winner
+  ``ScenarioMatrix`` run through ``BenchmarkRunner.run_matrix`` + winner
   selection into the DB;
 * ``tuning.db``     — the schema-tagged JSON DB ``kernels/*/ops.py``
   consult at trace time when callers pass no explicit block sizes;

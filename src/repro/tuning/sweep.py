@@ -3,11 +3,10 @@
 
 Tuning is deliberately NOT a bespoke timing loop: each candidate becomes
 one scenario (``arch`` = the candidate id, see ``tuning.space``) and the
-whole sweep dispatches through ``BenchmarkRunner.run_matrix`` — so it is
-embarrassingly parallel under ``jobs=N`` and ``cluster=`` for free, each
+whole sweep runs through ``BenchmarkRunner.run_matrix`` — so each
 candidate's time is a normal ``RunResult`` in the ``ResultStore``, and
-the measurement protocol (median-of-N, compile excluded, measurement
-fence under sharded dispatch) is exactly the one every other table uses.
+the measurement protocol (median-of-N, compile excluded) is exactly the
+one every other table uses.
 
 Winner selection: argmin of ``median_us`` over the case's OK cells, ties
 resolved toward the default.  Because the ops default is always

@@ -1,5 +1,5 @@
 """Batched prefill admission (PR 8): token equivalence against the
-per-request baseline across every dispatch mode, the recompile bound
+per-request baseline, alone and through run_matrix, the recompile bound
 from prompt-length bucketing, the knee-driven ``slots="auto"`` resolver,
 and the ``admission`` scenario axis."""
 import json
@@ -19,13 +19,13 @@ LOADGEN = dict(arch="gemma-2b", task="loadgen", batch=8, seq=8, slots=4,
                trace="bursty+bimodal", load=8.0)
 
 
-# ---- token equivalence across admission policies and dispatch modes -------
+# ---- token equivalence across admission policies ---------------------------
 
-def test_admission_policies_and_dispatch_modes_agree_on_tokens(tmp_path):
-    """The tentpole invariant, 4 ways: admission="single" (per-request
-    baseline), batched serial, batched under jobs=2 sharded dispatch, and
-    batched under cluster="local:2" all generate byte-identical tokens on
-    a mixed-length trace — batched admission is a pure scheduling change."""
+def test_admission_policies_agree_on_tokens(tmp_path):
+    """The tentpole invariant: admission="single" (per-request baseline)
+    and batched admission generate byte-identical tokens on a
+    mixed-length trace, run alone and as a run_matrix sweep — batched
+    admission is a pure scheduling change."""
     serial = BenchmarkRunner(runs=1, warmup=0)
     rb = serial.run(Scenario(**LOADGEN), record=False)
     rs = serial.run(Scenario(**LOADGEN, admission="single"), record=False)
@@ -53,17 +53,7 @@ def test_admission_policies_and_dispatch_modes_agree_on_tokens(tmp_path):
     assert len(matrix) == 2
     by_name = {rb.name: rb.extra["tokens"], rs.name: rs.extra["tokens"]}
 
-    sharded = BenchmarkRunner(runs=1, warmup=0, jobs=2)
-    try:
-        shard_rrs = sharded.run_matrix(matrix)
-    finally:
-        sharded.close()
-    clustered = BenchmarkRunner(runs=1, warmup=0)
-    try:
-        cluster_rrs = clustered.run_matrix(matrix, cluster="local:2")
-    finally:
-        clustered.close()
-    for rr in list(shard_rrs) + list(cluster_rrs):
+    for rr in serial.run_matrix(matrix):
         assert rr.status == "ok", f"{rr.name}: {rr.error}"
         assert rr.extra["tokens"] == by_name[rr.name], rr.name
 

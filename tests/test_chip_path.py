@@ -1,6 +1,7 @@
 """The chip entry point and the compile cache, checked without a chip:
-``chip_smoke.py`` refuses to run anywhere but a TPU, and
-``enable_compile_cache`` keeps JAX's persistent cache at one fixed path."""
+``chip_smoke.py`` refuses to run anywhere but a TPU,
+``enable_compile_cache`` keeps JAX's persistent cache at one fixed path,
+and the runner runs its cells without starting a Python process."""
 import json
 import os
 import shutil
@@ -78,3 +79,52 @@ def test_compile_cache_entries_land_in_env_dir(tmp_path):
     assert r.returncode == 0, r.stderr[-2000:]
     assert r.stdout.strip() == str(tmp_path)
     assert os.listdir(tmp_path)
+
+
+# ---- one process per chip ---------------------------------------------------
+
+@pytest.fixture(scope="module")
+def in_process_runner():
+    from repro.runner import BenchmarkRunner
+    return BenchmarkRunner(runs=1, warmup=0, compile_warmup=0)
+
+
+@pytest.mark.parametrize("cell", [
+    dict(task="train", batch=1, seq=8),
+    dict(task="infer_decode", batch=1, seq=8),
+    dict(task="serve", batch=2, seq=8, slots=2)],
+    ids=["train", "decode", "serve"])
+def test_runner_starts_no_python_process(in_process_runner, monkeypatch,
+                                         cell):
+    """A chip belongs to one process, so the runner must never hand a
+    cell to another: with every Python child refused (``subprocess.Popen``
+    of ``sys.executable``, ``multiprocessing`` process start), train,
+    decode and serve cells still run ok.  Provenance's ``git`` calls are
+    not Python processes and stay allowed."""
+    import multiprocessing.process
+
+    from repro.runner import Scenario
+    from repro.telemetry.provenance import collect
+    refused = []
+    real_popen = subprocess.Popen
+
+    class RefusingPopen(real_popen):
+        def __init__(self, args, *a, **kw):
+            argv = [args] if isinstance(args, (str, bytes)) else list(args)
+            if argv and str(argv[0]).startswith(sys.executable):
+                refused.append(argv)
+                raise AssertionError(f"started a Python process: {argv}")
+            super().__init__(args, *a, **kw)
+
+    def refuse_start(proc):
+        refused.append(proc)
+        raise AssertionError("started a multiprocessing process")
+
+    monkeypatch.setattr(subprocess, "Popen", RefusingPopen)
+    monkeypatch.setattr(multiprocessing.process.BaseProcess, "start",
+                        refuse_start)
+    collect(refresh=True)   # git runs through the guarded Popen
+    rr = in_process_runner.run(Scenario(arch="gemma-2b", **cell),
+                               record=False)
+    assert rr.status == "ok", rr.error
+    assert refused == []

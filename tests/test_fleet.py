@@ -7,7 +7,6 @@ so instrumentation assertions here always compare before/after deltas
 with ``>=`` — never absolute counts.
 """
 import json
-import os
 import re
 
 import pytest
@@ -20,16 +19,13 @@ from repro.fleet.scheduler import FleetConfig, FleetScheduler, VirtualClock
 from repro.fleet.service import FLEET_STATUS_SCHEMA_KEY, FleetService
 from repro.fleet.triage import triage
 from repro.runner import BenchmarkRunner, Scenario
-from repro.runner.protocol import stats_delta
 
 ARCH, SEQ = "gemma-2b", 8
 
 
 @pytest.fixture(scope="module")
 def runner():
-    r = BenchmarkRunner(runs=1, warmup=0)
-    yield r
-    r.close()
+    return BenchmarkRunner(runs=1, warmup=0)
 
 
 @pytest.fixture(scope="module")
@@ -48,13 +44,13 @@ def test_snapshot_schema_and_instruments():
     reg.inc("fleet_cells_total")
     reg.inc("fleet_cells_total", 2)
     reg.inc("fleet_cells_total", -5)          # negative deltas ignored
-    reg.set_gauge("pool_queue_depth", 3)
+    reg.set_gauge("serve_queue_depth", 3)
     reg.observe("fleet_measure_seconds", 0.5)
     snap = reg.snapshot()
     assert snap[METRICS_SCHEMA_KEY] == METRICS_SCHEMA_VERSION
     assert snap["ts"] > 0
     assert snap["counters"]["fleet_cells_total"] == 3
-    assert snap["gauges"]["pool_queue_depth"] == 3.0
+    assert snap["gauges"]["serve_queue_depth"] == 3.0
     hist = snap["histograms"]["fleet_measure_seconds"]
     assert hist["count"] == 1 and hist["sum"] == 0.5 and hist["max"] == 0.5
 
@@ -81,37 +77,12 @@ def test_disabled_registry_is_a_noop():
     assert not snap["counters"] and not snap["gauges"] and not snap["histograms"]
 
 
-def test_wire_round_trip_delta_merge():
-    """Worker-side cumulative snapshots delta-merge into a parent registry
-    with the stats_delta arithmetic: counters add exactly, histograms ship
-    count/sum, and a second snapshot only ships the increment."""
-    worker, parent, seen = MetricsRegistry(), MetricsRegistry(), {}
-    worker.inc("fleet_cells_total", 2)
-    worker.observe("fleet_measure_seconds", 1.0)
-    worker.set_gauge("pool_queue_depth", 7)   # gauges never cross the wire
-    parent.merge_cumulative(stats_delta(worker.counters_cumulative(), seen))
-    worker.inc("fleet_cells_total")
-    worker.observe("fleet_measure_seconds", 3.0)
-    parent.merge_cumulative(stats_delta(worker.counters_cumulative(), seen))
-    snap = parent.snapshot()
-    assert snap["counters"]["fleet_cells_total"] == 3
-    h = snap["histograms"]["fleet_measure_seconds"]
-    assert h["count"] == 2 and h["sum"] == 4.0
-    assert "pool_queue_depth" not in snap["gauges"]
-    # a worker respawn resets seen: the fresh process's counters must not
-    # be double-subtracted (delta of a fresh cumulative vs empty seen)
-    respawned, seen2 = MetricsRegistry(), {}
-    respawned.inc("fleet_cells_total")
-    parent.merge_cumulative(stats_delta(respawned.counters_cumulative(), seen2))
-    assert parent.snapshot()["counters"]["fleet_cells_total"] == 4
-
-
 def test_prometheus_exposition_parses():
     reg = MetricsRegistry()
     reg.inc("fleet_cells_total", 4)
     reg.set_gauge("serve_kv_occupancy", 0.25)
     reg.observe("fleet_compile_seconds", 1.5)
-    reg.set_gauge("cluster_inflight_local0:weird name", 1)  # needs sanitizing
+    reg.set_gauge("serve_slots_busy_slot0:weird name", 1)  # needs sanitizing
     text = reg.to_prometheus()
     sample = re.compile(r'^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^}]*\})? '
                         r'[-+0-9.eE]+$')
@@ -282,8 +253,8 @@ def test_supervisor_backoff_schedule(tmp_path):
 
 def test_service_crash_recovery_no_lost_history(tmp_path):
     """A tick that raises mid-run restarts under the supervisor with
-    backoff; completed ticks' history points survive, the replayed tick
-    logs its own, and the pool workers all die with close()."""
+    backoff; completed ticks' history points survive, and the replayed
+    tick logs its own."""
     fault = {"armed": True}
 
     def hooks_for_tick(tick):
@@ -295,17 +266,13 @@ def test_service_crash_recovery_no_lost_history(tmp_path):
         return None
 
     store = MetricStore(str(tmp_path / "store.json"))
-    runner = BenchmarkRunner(runs=1, warmup=0, jobs=2)
+    runner = BenchmarkRunner(runs=1, warmup=0)
     delays = []
     service = FleetService(
         _fleet_cfg(tmp_path), store=store, runner=runner,
         results_dir=str(tmp_path), clock=VirtualClock(),
         hooks_for_tick=hooks_for_tick, backoff_s=0.25, sleep=delays.append)
-    try:
-        summary = service.run(2)
-        pids = runner.worker_pids()
-    finally:
-        runner.close()
+    summary = service.run(2)
 
     assert summary["ticks"] == 2 and summary["restarts"] == 1
     assert delays == [0.25]
@@ -322,11 +289,6 @@ def test_service_crash_recovery_no_lost_history(tmp_path):
     assert status[FLEET_STATUS_SCHEMA_KEY] == 1
     assert status["ticks_done"] == 2 and status["restarts"] == 1
     assert len(status["ticks"]) == 2
-    # no orphan shard workers after close()
-    assert pids
-    for pid in pids:
-        with pytest.raises(ProcessLookupError):
-            os.kill(pid, 0)
 
 
 def test_service_metrics_disabled_toggle_restores():

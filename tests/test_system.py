@@ -1,6 +1,7 @@
 """End-to-end behaviour: training converges, fault-injected training is
 bit-identical to fault-free, serving generates, CI nightly detects injected
-regressions across the measured suite."""
+regressions across the measured suite, and run_matrix hands each
+regression hook to the cells it names."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -69,3 +70,49 @@ def test_nightly_ci_detects_injected_regression(tmp_path):
                        runner=runner)
     hits = [i for i in rep2.issues if i.metric == "median_us" and i.benchmark == "gemma-2b/train"]
     assert hits and hits[0].increase > max(0.07, 2 * noise)
+
+
+# ---- run_matrix's hook lookup -----------------------------------------------
+
+class _HookedCell(RegressionHook):
+    def fire(self):
+        raise RuntimeError("hooked cell")
+
+
+@pytest.fixture(scope="module")
+def hook_runner():
+    from repro.runner import BenchmarkRunner
+    return BenchmarkRunner(runs=1, warmup=0, compile_warmup=0)
+
+
+#: kind -> (matrix axes, the hooked cell's task); each matrix holds two
+#: cells of the hooked cell's bench and two of another bench
+HOOKED = {
+    "step": (dict(tasks=("train", "infer_decode"), batches=(1, 2)), "train"),
+    "serve": (dict(tasks=("serve", "train"), batches=(2, 3), slots=(2,)),
+              "serve"),
+}
+
+
+@pytest.mark.parametrize("key", ["name", "bench"])
+@pytest.mark.parametrize("kind", list(HOOKED))
+def test_run_matrix_hook_lookup(hook_runner, kind, key):
+    """Hooks are keyed by full scenario name or by bench ("arch/task"): a
+    name hook reaches its one cell, a bench hook every cell of the bench,
+    and no other cell sees either."""
+    from repro.runner import ScenarioMatrix
+    axes, task = HOOKED[kind]
+    matrix = ScenarioMatrix(archs=["gemma-2b"], seqs=(8,), **axes)
+    cells = hook_runner.select(matrix)
+    target = next(sc for sc in cells if sc.task == task)
+    results = hook_runner.run_matrix(
+        matrix, hooks={getattr(target, key): _HookedCell()})
+    assert [rr.name for rr in results] == [sc.name for sc in cells]
+    hooked = {sc.name for sc in cells
+              if getattr(sc, key) == getattr(target, key)}
+    assert len(hooked) == (1 if key == "name" else 2)
+    for rr in results:
+        if rr.name in hooked:
+            assert rr.status == "error" and "hooked cell" in rr.error, rr.name
+        else:
+            assert rr.status == "ok", f"{rr.name}: {rr.error}"

@@ -1,6 +1,6 @@
-"""The telemetry subsystem: span tracing stitched across dispatch
-transports, Chrome trace export, provenance stamping, the slots="auto"
-staleness warning, and the provenance-keyed result history."""
+"""The telemetry subsystem: span tracing of a run_matrix call, Chrome
+trace export, provenance stamping, the slots="auto" staleness warning,
+and the provenance-keyed result history."""
 import json
 import subprocess
 import sys
@@ -10,6 +10,7 @@ import pytest
 from repro.runner import (BenchmarkRunner, ResultStore, RunResult, Scenario,
                           ScenarioMatrix)
 from repro.runner.loadgen import DEFAULT_SLOTS, auto_slots_info
+from repro.runner.runner import _subprocess_env
 from repro.telemetry.export import chrome_trace, flame_summary, save_trace
 from repro.telemetry.history import drift, rolling_baseline, series, trajectory
 from repro.telemetry.provenance import (PROV_KEYS, collect, provenance_key,
@@ -49,28 +50,25 @@ def test_null_tracer_is_inert():
     with NULL_TRACER.span("x") as sp:
         pass
     NULL_TRACER.finish(sp)
-    assert NULL_TRACER.context(sp) is None
+    assert sp.to_dict() == {}
     assert NULL_TRACER.export() == []
 
 
 def test_chrome_trace_lanes_and_args():
-    tr = _synthetic_tracer()
-    tr.ingest([{"name": "cell:a/train", "span_id": "w-1.1",
-                "parent_id": None, "kind": "cell", "ts": 1.0,
-                "dur_s": 0.5, "tid": 7}], proc="shard0")
-    doc = chrome_trace(tr.export())
+    """Every span comes from the process that runs the cells, so the
+    export has one lane; ids, parents and attrs ride in args."""
+    doc = chrome_trace(_synthetic_tracer().export())
     events = [e for e in doc["traceEvents"] if e["ph"] == "X"]
-    meta = {e["args"]["name"]: e["pid"]
-            for e in doc["traceEvents"] if e["ph"] == "M"}
-    assert meta["coordinator"] == 0 and "shard0" in meta
-    assert len({e["pid"] for e in events}) == 2
-    cell = next(e for e in events if e["args"]["span_id"] == "w-1.1")
-    assert cell["pid"] == meta["shard0"]
-    assert cell["dur"] == pytest.approx(0.5e6)
+    meta = [e for e in doc["traceEvents"] if e["ph"] == "M"]
+    assert len(meta) == 1 and meta[0]["pid"] == 0
+    assert len(events) == 5
+    assert {(e["pid"], e["tid"]) for e in events} == {(0, 0)}
+    cell = next(e for e in events if e["name"] == "cell:a/train")
+    measure = next(e for e in events if e["name"] == "measure")
+    assert measure["dur"] == pytest.approx(0.75e6)
+    assert measure["args"]["parent"] == cell["args"]["span_id"]
     # attrs ride in args so the tree reconstructs from the file alone
-    coord_cell = next(e for e in events
-                      if e["name"] == "cell:a/train" and e["pid"] == 0)
-    assert coord_cell["args"]["cell"] == "a/train"
+    assert cell["args"]["cell"] == "a/train"
     json.dumps(doc)   # must be JSON-serializable as-is
 
 
@@ -80,29 +78,6 @@ def test_flame_summary_tree():
     assert lines[0].startswith("matrix")
     assert lines[1].startswith("  group:g0")
     assert "      build 250.0ms" in text and "measure 750.0ms" in text
-
-
-def test_worker_tracer_stitches_under_wire_parent():
-    """The full wire round-trip: a worker-side tracer built from the job's
-    trace context roots its spans under the coordinator's dispatch span,
-    and ingest relabels the lane to the worker's identity."""
-    coord = Tracer()
-    coord.begin_trace()
-    ds = coord.start("dispatch:a/train", kind="dispatch")
-    ctx = coord.context(ds)
-    worker = Tracer(trace_id=ctx["trace_id"], proc="worker",
-                    root_parent=ctx["parent"] or None)
-    with worker.span("cell:a/train", kind="cell") as c:
-        worker.add("build", ts=c.ts, dur_s=0.1, parent=c)
-    assert worker.trace_id == coord.trace_id
-    coord.ingest(worker.export(), proc="local0")
-    coord.finish(ds)
-    spans = coord.export()
-    cell = next(sp for sp in spans if sp["kind"] == "cell")
-    build = next(sp for sp in spans if sp["kind"] == "phase")
-    assert cell["parent_id"] == ds.span_id
-    assert build["parent_id"] == cell["span_id"]   # intra-worker untouched
-    assert cell["proc"] == build["proc"] == "local0"
 
 
 def test_group_label_is_stable():
@@ -119,7 +94,7 @@ def test_provenance_stamp_and_key():
     assert extra["prov_python"].count(".") == 2
     key = provenance_key(extra)
     assert key.endswith(f"/{extra['prov_backend']}/{extra['prov_host']}")
-    # setdefault semantics: a worker's stamp must not be overwritten
+    # setdefault semantics: an existing stamp must not be overwritten
     pre = {"prov_host": "measured-there"}
     stamp(pre)
     assert pre["prov_host"] == "measured-there"
@@ -264,7 +239,6 @@ def test_concurrent_provenance_appends_two_processes(tmp_path):
         "                  'median_us': float(i), 'ts': time.time(),\n"
         "                  'extra': extra})\n"
     )
-    from repro.runner.pool import _subprocess_env
     procs = []
     for commit in ("c1" * 20, "c2" * 20):
         env = _subprocess_env()
@@ -285,41 +259,75 @@ def test_concurrent_provenance_appends_two_processes(tmp_path):
 
 # ---- traced execution through the runner (jax) ----------------------------
 
-def test_jobs2_trace_stitches_worker_spans(tmp_path):
-    """A traced --jobs 2 matrix exports ONE Chrome trace where every
-    worker-side cell span nests under its coordinator dispatch span."""
-    matrix = ScenarioMatrix(archs=["gemma-2b"], tasks=("train",),
-                            batches=(1,), seqs=(8,),
-                            dtypes=("fp32", "bf16"))
-    runner = BenchmarkRunner(store=ResultStore(str(tmp_path / "s")),
-                             runs=1, warmup=0, jobs=2)
-    runner.tracer = Tracer()
-    try:
-        results = runner.run_matrix(matrix)
-    finally:
-        runner.close()
-    assert [rr.status for rr in results] == ["ok", "ok"]
-    for rr in results:
-        assert rr.extra["span_trace"] == runner.tracer.trace_id
-        assert rr.extra["span_dispatch"]
-        assert rr.extra["prov_commit"]
-    path = save_trace(runner.tracer.export(), str(tmp_path / "trace.json"))
+#: the traced serial matrices: one per task kind, plus a two-dtype matrix
+#: whose matrix order interleaves its two build keys
+TRACED = {
+    "train": dict(tasks=("train",), batches=(1, 2)),
+    "prefill": dict(tasks=("infer_prefill",), batches=(1, 2)),
+    "decode": dict(tasks=("infer_decode",), batches=(1, 2)),
+    "serve": dict(tasks=("serve",), batches=(2, 3), slots=(2,)),
+    "two_dtypes": dict(tasks=("train",), batches=(1, 2),
+                       dtypes=("fp32", "bf16")),
+}
+
+
+@pytest.fixture(scope="module")
+def traced_runner():
+    return BenchmarkRunner(runs=1, warmup=0)
+
+
+@pytest.mark.parametrize("kind", list(TRACED))
+def test_serial_matrix_exports_one_nested_trace(traced_runner, tmp_path,
+                                                kind):
+    """A traced run_matrix call exports one Chrome trace: the matrix
+    root, one group per build key (stitched after the loop, since cells
+    interleave across keys), the cells under their group, and each
+    cell's phases under the cell."""
+    matrix = ScenarioMatrix(archs=["gemma-2b"], seqs=(8,), **TRACED[kind])
+    traced_runner.tracer = tracer = Tracer()
+    scenarios = traced_runner.select(matrix)
+    results = traced_runner.run_matrix(matrix)
+    assert [rr.status for rr in results] == ["ok"] * len(scenarios), \
+        [rr.error for rr in results]
+    keys = [sc.build_key() for sc in scenarios]
+    if kind == "two_dtypes":
+        assert len(scenarios) == 4
+        assert keys[0] == keys[2] != keys[1] == keys[3]
+    path = save_trace(tracer.export(), str(tmp_path / "trace.json"))
     with open(path) as f:
         doc = json.load(f)
-    events = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+    events = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert {(e["pid"], e["tid"]) for e in events} == {(0, 0)}
     by_id = {e["args"]["span_id"]: e for e in events}
-    assert len({e["pid"] for e in events}) >= 3   # coordinator + 2 shards
-    worker_cells = [e for e in events
-                    if e["args"].get("kind") == "cell" and e["pid"] != 0]
-    assert len(worker_cells) >= 2
-    dispatched = set()
-    for cell in worker_cells:
-        parent = by_id[cell["args"]["parent"]]
-        assert parent["args"]["kind"] == "dispatch"
-        assert parent["pid"] == 0                  # coordinator lane
-        assert parent["args"]["cell"] == cell["args"]["cell"]
-        dispatched.add(parent["args"]["cell"])
-    assert dispatched == {rr.name for rr in results}
+
+    def parent(e):
+        return by_id.get(e["args"]["parent"])
+
+    roots = [e for e in events if parent(e) is None]
+    assert len(roots) == 1
+    root = roots[0]
+    assert root["name"] == "matrix" and root["args"]["kind"] == "matrix"
+    assert root["args"]["cells"] == len(scenarios)
+    assert "transport" not in root["args"]
+    groups = [e for e in events if e["args"]["kind"] == "group"]
+    assert sorted(g["name"] for g in groups) == \
+        sorted({"group:" + group_label(k) for k in keys})
+    assert all(parent(g) is root for g in groups)
+    cells = [e for e in events if e["args"]["kind"] == "cell"]
+    assert len(cells) == len(scenarios)
+    for sc, rr in zip(scenarios, results):
+        assert rr.extra["span_trace"] == tracer.trace_id
+        cell = by_id[rr.extra["span_cell"]]
+        assert cell["args"]["cell"] == sc.name
+        assert parent(cell)["name"] == "group:" + group_label(sc.build_key())
+        phases = [e for e in events if parent(e) is cell]
+        assert {p["args"]["kind"] for p in phases} == {"phase"}
+        assert {"build", "measure"} <= {p["name"] for p in phases}
+    # whatever lies deeper (the serve engine's admit.*/decode.* spans)
+    # nests under a phase
+    for e in events:
+        if e["args"]["kind"] not in ("matrix", "group", "cell", "phase"):
+            assert parent(e)["args"]["kind"] == "phase", e["name"]
 
 
 def test_span_overhead_on_warm_executable():
@@ -329,16 +337,13 @@ def test_span_overhead_on_warm_executable():
     sc = Scenario(arch="gemma-2b", task="train", batch=1, seq=8)
     runner = BenchmarkRunner(runs=1, warmup=1)
     counts = {}
-    try:
-        runner.run(sc, record=False)   # build + compile once
-        for runs in (1, 5):
-            runner.tracer = Tracer()
-            rr = runner.run(sc, runs=runs, record=False)
-            assert rr.status == "ok" and rr.runs == runs, rr.error
-            names = [sp["name"] for sp in runner.tracer.export()]
-            counts[runs] = {n: names.count(n) for n in names}
-    finally:
-        runner.close()
+    runner.run(sc, record=False)   # build + compile once
+    for runs in (1, 5):
+        runner.tracer = Tracer()
+        rr = runner.run(sc, runs=runs, record=False)
+        assert rr.status == "ok" and rr.runs == runs, rr.error
+        names = [sp["name"] for sp in runner.tracer.export()]
+        counts[runs] = {n: names.count(n) for n in names}
     assert counts[1] == counts[5], counts
     assert counts[1]["measure"] == 1
 
@@ -350,10 +355,7 @@ def test_provenance_on_every_status(tmp_path):
                             tasks=("train",), batches=(1,), seqs=(8,))
     store = ResultStore(str(tmp_path / "s"))
     runner = BenchmarkRunner(store=store, runs=1, warmup=0)
-    try:
-        results = runner.run_matrix(matrix)
-    finally:
-        runner.close()
+    results = runner.run_matrix(matrix)
     assert {rr.status for rr in results} == {"ok", "error"}
     recs = list(store.history())
     assert len(recs) == 2
