@@ -145,8 +145,11 @@ class ServeEngine:
         # vlm prefill writes n_prefix patch tokens ahead of the prompt, so
         # a slot's cache position starts past the prefix after admission
         self._prefix = built.cfg.n_prefix if built.cfg.family == "vlm" else 0
-        self._decode = jax.jit(self.model.decode_step,
-                               donate_argnums=(2,) if donate else ())
+        self._decode_step = jax.jit(self.model.decode_step,
+                                    donate_argnums=(2,) if donate else ())
+        # the rows that decode, on the device; run() re-sends it when they
+        # change
+        self._active = jnp.zeros((slots,), bool)
         self._admit = jax.jit(self._admit_impl,
                               donate_argnums=(5,) if donate else ())
         # per-leaf batch axis of every cache leaf, from the declared
@@ -268,12 +271,17 @@ class ServeEngine:
                 first_by_slot[s] = int(first[i])
         return [first_by_slot[s] for s, _ in pairs]
 
+    def _decode(self, params, tokens, cache):
+        """The jitted decode step over the rows ``self._active`` marks."""
+        return self._decode_step(params, tokens, cache, self._active)
+
     def lowered_decode(self):
         """Lower the jitted decode step against the engine's live state —
         the profiler's attribution source (lowering an already-traced call
         is ~1 ms; the caller pays/caches the AOT compile)."""
         toks = jnp.zeros((self.slots, 1), jnp.int32)
-        return self._decode.lower(self.params, toks, self.cache)
+        return self._decode_step.lower(self.params, toks, self.cache,
+                                       self._active)
 
     def run(self, requests: List[Request], *, hook=None,
             span_log: Optional[list] = None) -> Dict[str, Any]:
@@ -293,9 +301,10 @@ class ServeEngine:
         ``span_log`` is the tracing hook: it receives ``(name, t0, t1)``
         or ``(name, t0, t1, attrs)`` tuples that tile the loop's host work
         without overlapping.  Each decode step logs ``DECODE_PHASES`` in
-        order: ``decode.prepare`` (the KV-exhaustion guard and the token
-        upload), ``decode.dispatch`` (the jitted decode call, until it
-        returns), ``decode.sync`` (the argmax and its readback: the host
+        order: ``decode.prepare`` (the KV-exhaustion guard, the token
+        upload, and the busy-row mask's when the busy rows changed),
+        ``decode.dispatch`` (the jitted decode call, until it returns),
+        ``decode.sync`` (the argmax and its readback: the host
         waits on the device) and ``decode.commit`` (appending tokens,
         finishing requests, advancing the rows).  ``hook.fire()`` runs
         between sync and commit, outside every span: its time is the
@@ -324,6 +333,8 @@ class ServeEngine:
         tok_lat_s: List[float] = []
         qdepth: List[int] = []
         waves = 0
+        sent: Optional[List[int]] = None   # the busy rows self._active marks
+        mask_uploads = 0
         t = 0.0      # start of the open span, when span_log is given
         t0 = time.perf_counter()
         while done_count < total:
@@ -373,11 +384,13 @@ class ServeEngine:
                 continue
             if span_log is not None:
                 t = time.time()
+            busy = []
             for s in range(self.slots):
                 req = self.slot_req[s]
                 if req is None or req.done:
                     continue   # idle rows may overflow harmlessly (clamped
                     #            write, row fully rewritten at next admit)
+                busy.append(s)
                 if self.slot_pos[s] + 1 > self.max_len:
                     raise RuntimeError(
                         f"KV cache exhausted: slot {s} (rid {req.rid}) at "
@@ -385,6 +398,12 @@ class ServeEngine:
                         f"{self.max_len} — size the engine with "
                         f"traces.cache_len_bound() for the trace")
             ts = time.perf_counter()
+            if busy != sent:
+                mask = np.zeros(self.slots, bool)
+                mask[busy] = True
+                self._active = jnp.asarray(mask)
+                sent = busy
+                mask_uploads += 1
             toks = jnp.asarray(next_tok[:, None])
             if span_log is not None:
                 t = _mark(span_log, "decode.prepare", t)
@@ -444,6 +463,9 @@ class ServeEngine:
                 # reached bucket shapes no earlier replay had) and its wall/
                 # TTFT samples are not steady-state — rerun to re-measure
                 "admit_new_shapes": len(self._admit_shapes) - shapes0,
+                # how often the busy-row mask went to the device: once per
+                # change of the busy rows, not per decode step
+                "decode_mask_uploads": mask_uploads,
                 "tokens_by_rid": tokens_by_rid(requests)}
 
     def capture(self, requests: List[Request], *, seed: int = 0,

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed import logical
+from repro.kernels.ssd.decode import busy_rows
 from repro.models import layers as L
 from repro.models import mla, moe, rglru, ssm
 from repro.models.layers import ParamDef
@@ -90,11 +91,11 @@ def _rec_block_defs(cfg, lp):
             "ln2": _norm_def(cfg, lp), "mlp": L.ffn_defs(cfg, None, lp)}
 
 
-def _mamba_block(p, x, cfg, *, cache, seq_lens=None, layer=None):
+def _mamba_block(p, x, cfg, *, cache, seq_lens=None, layer=None, busy=None):
     with jax.named_scope("ssm"):
         h = L.rms_norm(x, p["ln"], cfg.norm_eps)
         h, new_c = ssm.mamba2_block(p["mix"], h, cfg, cache=cache,
-                                    seq_lens=seq_lens, layer=layer)
+                                    seq_lens=seq_lens, layer=layer, busy=busy)
         x = x + h
     return logical(x, ("act_batch", "act_seq", "act_embed")), new_c
 
@@ -288,7 +289,7 @@ class Model:
         return x, (ys if has_cache else None)
 
     def _run_layers(self, params, x, positions, cache, kind_override=None,
-                    enc_out=None, seq_lens=None):
+                    enc_out=None, seq_lens=None, active=None):
         cfg = self.cfg
         fam = cfg.family
         new_cache: Dict[str, Any] = {}
@@ -336,9 +337,12 @@ class Model:
                     new_cache["blocks"] = c_new
 
         elif fam == "ssm":
+            # the busy rows, compacted once for every layer's kernel
+            busy = None if active is None else busy_rows(active)
+
             def body(p_i, x, c_i, layer=None):
                 return _mamba_block(p_i, x, cfg, cache=c_i, seq_lens=seq_lens,
-                                    layer=layer)
+                                    layer=layer, busy=busy)
             c = cache.get("blocks") if cache else None
             x, c_new = self._scan_stack(body, x, params["blocks"], c,
                                         in_place={"ssm"})
@@ -589,17 +593,20 @@ class Model:
         blocks["cross_v"] = cv.astype(cache["blocks"]["cross_v"].dtype)
         return {**cache, "blocks": blocks}
 
-    def decode_step(self, params, tokens, cache):
+    def decode_step(self, params, tokens, cache, active=None):
         """tokens (B, 1) -> (logits (B,1,V), new cache).
 
         Positions are per-row: each batch row decodes at its own cache
         offset (the ``len`` vector), so a continuous-batching decode step
-        can mix rows whose prompts had different lengths."""
-        cfg = self.cfg
+        can mix rows whose prompts had different lengths.
+
+        ``active`` (B,) bool marks the rows that decode: the ssm family
+        leaves every other row's SSM state as it is (a free row's logits
+        mean nothing); every other family ignores it, and a jit prunes it."""
         pos = self._cache_len(cache)            # (B,)
         positions = pos[:, None] + jnp.arange(1)  # (B, 1)
         x = self._embed(params, tokens, positions)
-        x, cache = self._run_layers(params, x, positions, cache)
+        x, cache = self._run_layers(params, x, positions, cache, active=active)
         return self._head(params, x), cache
 
     def _cache_len(self, cache):

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed import logical
-from repro.kernels.ssd.decode import ssd_decode
+from repro.kernels.ssd.decode import Busy, ssd_decode
 from repro.models.layers import ParamDef, causal_conv1d, rms_norm
 
 
@@ -115,20 +115,26 @@ def ssd_step(state, xt, dtt, A, bt, ct):
     return y.astype(xt.dtype), state
 
 
-def ssd_step_stacked(stack, layer, xt, dtt, A, bt, ct):
+def ssd_step_stacked(stack, layer, xt, dtt, A, bt, ct, busy: Optional[Busy] = None):
     """``ssd_step`` on layer ``layer`` of the stacked state (L, B, H, P, N).
 
     Returns y and the stack with that layer's slice stepped in place.
     Lowered for a TPU, one Pallas kernel (``repro.kernels.ssd.decode``)
     reads and writes each state tile once; on any other platform the
-    slice goes through ``ssd_step`` and is written back."""
+    slice goes through ``ssd_step`` and is written back.  With ``busy``
+    (``busy_rows``) a free row's slice keeps its values and its y is
+    zero: on the TPU its tile is not touched at all."""
     def xla(stack, layer, *args):
-        y, h = ssd_step(jax.lax.dynamic_index_in_dim(stack, layer, keepdims=False),
-                        *args)
+        h0 = jax.lax.dynamic_index_in_dim(stack, layer, keepdims=False)
+        y, h = ssd_step(h0, *args)
+        if busy is not None:
+            h = jnp.where(busy.mask[:, None, None, None], h, h0)
+            y = jnp.where(busy.mask[:, None, None], y, 0)
         return y, jax.lax.dynamic_update_index_in_dim(stack, h, layer, 0)
 
     return jax.lax.platform_dependent(stack, layer, xt, dtt, A, bt, ct,
-                                      tpu=partial(ssd_decode, interpret=False),
+                                      tpu=partial(ssd_decode, busy=busy,
+                                                  interpret=False),
                                       default=xla)
 
 
@@ -179,12 +185,15 @@ def _split_in_proj(zxbcdt, cfg):
 
 def mamba2_block(p: dict, u: jax.Array, cfg, cache: Optional[dict] = None,
                  seq_lens: Optional[jax.Array] = None,
-                 layer: Optional[jax.Array] = None):
+                 layer: Optional[jax.Array] = None,
+                 busy: Optional[Busy] = None):
     """u (B, S, E) -> (y, new_cache).
 
     At decode (S == 1 with a cache) ``cache["ssm"]`` is the whole stacked
     state and ``layer`` this layer's index in it; the new cache holds the
-    whole stack back (``Model._scan_stack``'s ``in_place``).
+    whole stack back (``Model._scan_stack``'s ``in_place``).  ``busy``
+    (``repro.kernels.ssd.decode.busy_rows``) names the rows that decode:
+    the state of every other row is left as it is.
 
     ``seq_lens`` (B,) marks each row's valid prefix under right-padded
     batched prefill: pad steps become identity SSD updates (dt=0 -> decay 1,
@@ -217,7 +226,7 @@ def mamba2_block(p: dict, u: jax.Array, cfg, cache: Optional[dict] = None,
     new_cache = None
     if cache is not None and S == 1:
         y, new_state = ssd_step_stacked(cache["ssm"], layer, x[:, 0], dt[:, 0], A,
-                                        Bm[:, 0], Cm[:, 0])
+                                        Bm[:, 0], Cm[:, 0], busy)
         y = y[:, None]
         new_cache = {"conv": new_conv, "ssm": new_state, "len": cache["len"] + 1}
     else:

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.ssd.decode import ssd_decode
+from repro.kernels.ssd.decode import busy_rows, ssd_decode
 from repro.models.rglru import rglru_scan, rglru_step
 from repro.models.ssm import ssd_chunked, ssd_sequential, ssd_step, ssd_step_stacked
 
@@ -54,16 +54,8 @@ def test_ssd_carried_state_across_segments():
     np.testing.assert_allclose(np.asarray(h2), np.asarray(h_full), atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("layer", [0, 1, 2])
-@pytest.mark.parametrize("H", [16, 80])
-@pytest.mark.parametrize("branch", ["kernel", "xla"])
-def test_ssd_decode_steps_one_layer_of_the_stack(branch, H, layer):
-    """One decode step on one layer of a stacked f32 state, at mamba2's
-    P=64, N=128 with bf16 inputs (80 heads is mamba2-2.7b's count): the
-    Pallas kernel (interpreted here) and the XLA branch that
-    ``ssd_step_stacked`` takes off the TPU both match ``ssd_step`` on that
-    layer's slice, and leave every other layer's slice bit for bit."""
-    L, B, P, N = 3, 2, 64, 128
+def _decode_inputs(L, B, H, P=64, N=128):
+    """A stacked f32 state and one decode step's bf16 inputs."""
     k = jax.random.split(jax.random.key(7), 6)
     stack = jax.random.normal(k[0], (L, B, H, P, N), jnp.float32)
     x = jax.random.normal(k[1], (B, H, P)).astype(jnp.bfloat16)
@@ -71,22 +63,66 @@ def test_ssd_decode_steps_one_layer_of_the_stack(branch, H, layer):
     A = -jnp.exp(jax.random.normal(k[3], (H,)) * 0.3)
     bm = jax.random.normal(k[4], (B, N)).astype(jnp.bfloat16)
     cm = jax.random.normal(k[5], (B, N)).astype(jnp.bfloat16)
-    if branch == "xla":
-        step = jax.jit(ssd_step_stacked)
-    else:
-        step = jax.jit(partial(ssd_decode, interpret=True))
-    y, new = step(stack, layer, x, dt, A, bm, cm)
+    return stack, x, dt, A, bm, cm
+
+
+def _stepper(branch):
+    """``(stack, layer, x, dt, A, bm, cm, active=None) -> (y, stack)``,
+    ``active`` a (B,) bool mask of the busy rows: the Pallas kernel
+    (interpreted here), or the XLA branch that ``ssd_step_stacked`` takes
+    off the TPU."""
+    step = ssd_step_stacked if branch == "xla" else partial(ssd_decode, interpret=True)
+    return jax.jit(lambda *a, active=None: step(
+        *a, busy=None if active is None else busy_rows(active)))
+
+
+#: busy-row masks over four slots, by case name
+MASKS = {"all": [1, 1, 1, 1], "none": [0, 0, 0, 0], "ends-free": [0, 1, 1, 0],
+         "one-in-the-middle": [0, 0, 1, 0], "alternating": [1, 0, 1, 0]}
+
+
+@pytest.mark.parametrize("branch,H,layer,mask", [
+    pytest.param(branch, H, layer, None, id=f"{branch}-{H}-{layer}")
+    for branch in ("kernel", "xla") for H in (16, 80) for layer in (0, 1, 2)] + [
+    pytest.param(branch, 16, 1, mask, id=f"{branch}-{name}")
+    for branch in ("kernel", "xla") for name, mask in MASKS.items()])
+def test_ssd_decode_steps_one_layer_of_the_stack(branch, H, layer, mask):
+    """One decode step on one layer of a stacked f32 state, at mamba2's
+    P=64, N=128 with bf16 inputs (80 heads is mamba2-2.7b's count): the
+    Pallas kernel (interpreted here) and the XLA branch that
+    ``ssd_step_stacked`` takes off the TPU both match ``ssd_step`` on that
+    layer's slice, and leave every other layer's slice bit for bit.
+
+    Given a busy-row mask, both step only the busy rows: a free row's
+    slice is the input bit for bit and its y is zero.  With every row
+    busy the result is the unmasked step's."""
+    B = 2 if mask is None else len(mask)
+    stack, x, dt, A, bm, cm = _decode_inputs(3, B, H)
+    step = _stepper(branch)
+    active = None if mask is None else jnp.asarray(mask, bool)
+    y, new = step(stack, layer, x, dt, A, bm, cm, active=active)
+    rows = np.arange(B) if mask is None else np.flatnonzero(mask)
     y_ref, h_ref = ssd_step(stack[layer], x, dt, A, bm, cm)
     assert y.dtype == jnp.bfloat16 and new.dtype == jnp.float32
     # the same f32 products and sums, up to their order and fusion
-    h_ref = np.asarray(h_ref)
-    assert np.max(np.abs(np.asarray(new[layer]) - h_ref)) <= 1e-5 * np.max(np.abs(h_ref))
+    h_ref = np.asarray(h_ref)[rows]
+    assert np.max(np.abs(np.asarray(new[layer])[rows] - h_ref),
+                  initial=0.0) <= 1e-5 * np.max(np.abs(h_ref), initial=0.0)
     # y is rounded to bf16 from f32 sums that may differ in order: each side
     # rounds by at most 2^-8 of the value
-    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y_ref, np.float32),
+    np.testing.assert_allclose(np.asarray(y, np.float32)[rows],
+                               np.asarray(y_ref, np.float32)[rows],
                                rtol=2 ** -7, atol=1e-6)
-    for other in set(range(L)) - {layer}:
+    free = np.setdiff1d(np.arange(B), rows)
+    np.testing.assert_array_equal(np.asarray(new[layer])[free],
+                                  np.asarray(stack[layer])[free])
+    assert not np.asarray(y, np.float32)[free].any()
+    for other in set(range(3)) - {layer}:
         np.testing.assert_array_equal(np.asarray(new[other]), np.asarray(stack[other]))
+    if mask is not None and all(mask):
+        y0, new0 = step(stack, layer, x, dt, A, bm, cm)
+        np.testing.assert_array_equal(np.asarray(new), np.asarray(new0))
+        np.testing.assert_array_equal(np.asarray(y, np.float32), np.asarray(y0, np.float32))
 
 
 def test_rglru_scan_matches_step_chain():

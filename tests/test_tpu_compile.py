@@ -113,10 +113,11 @@ def test_gemma_2b_full_width_decode_fits_one_chip(one_chip):
     assert used < V5E_HBM_BYTES / 2, used
 
 
-def _decode_step(sharding, arch, layers, slots):
+def _decode_step(sharding, arch, layers, slots, masked=False):
     """The serve engine's decode step as the chip benchmark serves it (bf16
-    weights, max_len 1024, the cache donated), compiled for ``sharding``:
-    (model, abstract cache, HLO text)."""
+    weights, max_len 1024, the cache donated, with the busy-row mask if
+    ``masked``), compiled for ``sharding``: (model, abstract cache, HLO
+    text)."""
     cfg = dataclasses.replace(get_arch(arch), n_layers=layers,
                               param_dtype=jnp.bfloat16)
     model = build_model(cfg)
@@ -125,9 +126,10 @@ def _decode_step(sharding, arch, layers, slots):
         return jax.tree.map(lambda s: _sds(sharding, s.shape, s.dtype), tree)
 
     cache = placed(abstract_tree(model.cache_defs(slots, 1024)))
+    mask = [_sds(sharding, (slots,), jnp.bool_)] if masked else []
     text = jax.jit(model.decode_step, donate_argnums=(2,)).lower(
         placed(model.abstract_params()), _sds(sharding, (slots, 1), jnp.int32),
-        cache).compile().as_text()
+        cache, *mask).compile().as_text()
     return model, cache, text
 
 
@@ -145,13 +147,14 @@ def test_decode_step_updates_the_donated_cache_in_place(one_chip, arch,
     has the shape of a stacked cache leaf: the layer scan updates the
     donated buffer in place instead of copying it whole before the loop.
     (An asynchronous ``copy-start`` moves a buffer between memory spaces,
-    a prefetch; it is not counted.)"""
-    _, cache, text = _decode_step(one_chip, arch, layers, slots)
-    stacked = {",".join(map(str, leaf.shape)) for leaf in jax.tree.leaves(cache)}
-    copies = [m.group(0) for m in re.finditer(
-        r"%\S+ = \w+\[([\d,]*)\]\S* copy\(", text)
-        if m.group(1) in stacked]
-    assert not copies, copies
+    a prefetch; it is not counted.)  With the busy-row mask too."""
+    for masked in (False, True):
+        _, cache, text = _decode_step(one_chip, arch, layers, slots, masked)
+        stacked = {",".join(map(str, leaf.shape)) for leaf in jax.tree.leaves(cache)}
+        copies = [m.group(0) for m in re.finditer(
+            r"%\S+ = \w+\[([\d,]*)\]\S* copy\(", text)
+            if m.group(1) in stacked]
+        assert not copies, copies
 
 
 #: one HLO instruction: name, result shape, opcode, operands
@@ -165,20 +168,26 @@ def test_decode_step_steps_the_ssm_state_in_one_kernel(one_chip, arch,
     """mamba2's compiled decode step steps the stacked f32 SSM state in the
     ``ssd_decode`` Pallas kernel, and no fusion reads the stack: XLA's
     update and its separate ``y`` pass, which read the state twice, are
-    gone.  The dense family's decode step holds no such kernel."""
-    model, cache, text = _decode_step(one_chip, arch, layers, slots)
-    shapes, readers = {}, []
-    for name, shape, opcode, operands in _INSTRUCTION.findall(text):
-        shapes[name] = shape
-        readers.append((name, opcode, [o.strip().lstrip("%") for o in operands.split(",")]))
-    kernels = [name for name, opcode, _ in readers
-               if opcode == "custom-call" and name.startswith("ssd_decode")]
-    if model.cfg.family != "ssm":
-        assert not kernels
-        return
-    state = "f32[%s]" % ",".join(map(str, cache["blocks"]["ssm"].shape))
-    assert state == "f32[%d,%d,80,64,128]" % (layers, slots)
-    reads = [(name, opcode) for name, opcode, operands in readers
-             if any(shapes.get(o, "").startswith(state + "{") for o in operands)]
-    assert kernels and {name for name, _ in reads if name in kernels}, reads
-    assert not [r for r in reads if r[1] == "fusion"], reads
+    gone.  So it does with the serve engine's busy-row mask, which the
+    kernel reads to skip free rows: the mask adds no pass over the state.
+    The dense family's decode step holds no such kernel."""
+    for masked in (False, True):
+        model, cache, text = _decode_step(one_chip, arch, layers, slots, masked)
+        shapes, readers = {}, []
+        for name, shape, opcode, operands in _INSTRUCTION.findall(text):
+            shapes[name] = shape
+            readers.append((name, opcode,
+                            [o.strip().lstrip("%") for o in operands.split(",")]))
+        kernels = [name for name, opcode, _ in readers
+                   if opcode == "custom-call" and name.startswith("ssd_decode")]
+        if model.cfg.family != "ssm":
+            assert not kernels
+            continue
+        # one kernel call, in the body of the layer scan: one per layer
+        assert len(kernels) == 1, kernels
+        state = "f32[%s]" % ",".join(map(str, cache["blocks"]["ssm"].shape))
+        assert state == "f32[%d,%d,80,64,128]" % (layers, slots)
+        reads = [(name, opcode) for name, opcode, operands in readers
+                 if any(shapes.get(o, "").startswith(state + "{") for o in operands)]
+        assert {name for name, _ in reads if name in kernels}, reads
+        assert not [r for r in reads if r[1] == "fusion"], reads
